@@ -107,7 +107,8 @@ def run_bounded_to_target(stepper) -> Stats:
     utils/telemetry.py) the run fn additionally threads the device-resident
     per-window History through the loop -- `_run_fn(state, key, target,
     until, hist) -> (state, hist)` -- and the per-call wall clock lands in
-    the session's phase ledger (first call = compile, rest = execute).
+    the session's phase ledger (the first call's dispatch = compile_s, the
+    rest = execute_s; TelemetrySession).
     """
     import time
 
@@ -128,12 +129,12 @@ def run_bounded_to_target(stepper) -> Stats:
     while True:
         until = min(cfg.max_rounds, tick + budget)
         t0 = time.perf_counter()
-        # Span per bounded device call: the first one is dominated by
-        # trace+compile (the telemetry ledger's compile_s), later ones are
-        # pure execution -- the name says which, so the trace separates
-        # compile cost from steady-state throughput at a glance.
-        with _trace.span("phase2.compile+run" if calls == 0
-                         else "phase2.bounded_call", cat="device") as sp:
+        # Two spans per bounded device call, where the host work splits:
+        # `dispatch` is the jitted call up to its return (on a fresh
+        # stepper's first call: trace, lowering, compile or persistent-cache
+        # load, then the enqueue), `sync` the device_get that waits for the
+        # device to finish the call.
+        with _trace.span("phase2.dispatch", cat="device", call=calls):
             if hist is not None:
                 stepper.state, hist = stepper._run_fn(
                     stepper.state, stepper.key, np.int32(target),
@@ -142,6 +143,8 @@ def run_bounded_to_target(stepper) -> Stats:
                 stepper.state = stepper._run_fn(
                     stepper.state, stepper.key,
                     np.int32(target), np.int32(until))
+        dispatch_s = time.perf_counter() - t0
+        with _trace.span("phase2.sync", cat="device", call=calls) as sp:
             st = stepper.state
             from gossip_simulator_tpu.models.event import \
                 in_flight as _inflight
@@ -159,7 +162,7 @@ def run_bounded_to_target(stepper) -> Stats:
                           in_flight=in_flight)
         calls += 1
         if telem is not None:
-            telem.tally_gossip_call(time.perf_counter() - t0)
+            telem.tally_gossip_call(dispatch_s, time.perf_counter() - t0)
         # Exhaustion is recorded whatever ends the run (the windowed loop's
         # per-window flag ends up reflecting the LAST window too), so a wave
         # that dies in the same window the round cap is hit still reports

@@ -175,6 +175,7 @@ class JaxStepper(Stepper):
                 t0 = time.perf_counter()
                 with _trace.span("phase1.split_round", cat="device"):
                     self._advance_overlay()
+                dispatch_s = time.perf_counter() - t0
                 self._overlay_rounds += 1
                 self._phase1_ms = self._overlay_rounds * self._mean_delay
                 # Round 7: with the dead-row skip on, the split round
@@ -190,7 +191,8 @@ class JaxStepper(Stepper):
                          st.mailbox_dropped))
                     telem.overlay_host_row(
                         [self._overlay_rounds, int(mk), int(bk), int(dr)])
-                    telem.tally_overlay_call(time.perf_counter() - t0)
+                    telem.tally_overlay_call(dispatch_s,
+                                             time.perf_counter() - t0)
                     q = (bool(q_fast) if q_fast is not None
                          else bool(jax.device_get(oq(self.ostate))))
                 else:
@@ -218,15 +220,16 @@ class JaxStepper(Stepper):
             if lim <= 0:
                 break
             t0 = time.perf_counter()
-            with _trace.span("phase1.compile+run" if calls == 0
-                             else "phase1.bounded_call",
-                             cat="device") as sp:
+            # dispatch / sync split: see base.run_bounded_to_target.
+            with _trace.span("phase1.dispatch", cat="device", call=calls):
                 if hist is not None:
                     self.ostate, polls, q, hist = self._orun(
                         self.ostate, self.key, np.int32(lim), hist)
                 else:
                     self.ostate, polls, q = self._orun(
                         self.ostate, self.key, np.int32(lim))
+            dispatch_s = time.perf_counter() - t0
+            with _trace.span("phase1.sync", cat="device", call=calls) as sp:
                 faithful = self._faithful_overlay
                 tick = self.ostate.tick if faithful else 0
                 polls, q, tick = jax.device_get((polls, q, tick))
@@ -234,7 +237,7 @@ class JaxStepper(Stepper):
                     sp.update(windows=int(polls))
             calls += 1
             if telem is not None:
-                telem.tally_overlay_call(time.perf_counter() - t0)
+                telem.tally_overlay_call(dispatch_s, time.perf_counter() - t0)
             self._overlay_rounds += int(polls)
             self._phase1_ms = (float(tick) if faithful
                                else self._overlay_rounds * self._mean_delay)

@@ -243,19 +243,17 @@ class Config:
     # "auto" picks double on multi-device meshes and off elsewhere
     # (S=1 skips the collective entirely, nothing to overlap).
     exchange_pipeline: str = "auto"
-    # Emit a TensorBoard trace of the epidemic phase.
-    profile: bool = False
-    profile_dir: str = "/tmp/gossip-trace"
     # --- flight recorder (utils/trace.py, utils/artifact.py) -----------------
-    # Host-side span trace (compile, phase-1 rounds, phase-2 windows,
-    # checkpoint save/load, sharded exchange) as Chrome trace-event JSON
-    # to this path.  Pure host-side observability: the traced jitted
-    # programs are unchanged, so trajectories stay bit-identical.
+    # Host-side span trace (phase-1 rounds, phase-2 windows and bounded
+    # calls, checkpoint save/load, sharded exchange) as Chrome trace-event
+    # JSON to this path, on the profiler's clock.  Pure host-side
+    # observability: the traced jitted programs are unchanged, so
+    # trajectories stay bit-identical.
     trace: str = ""
-    # jax.profiler device profile wrapping the whole run, with a
-    # TraceAnnotation per host span so the TensorBoard device timeline
-    # lines up with the -trace spans (unlike -profile, which wraps only
-    # phase 2 and carries no span names).
+    # jax.profiler device profile (xplane) wrapping the whole run: every
+    # span is a TraceAnnotation whenever a profiler runs, and the ring
+    # engine's ops carry named scopes, so device and host timelines line
+    # up with the -trace spans.
     xprof_dir: str = ""
     # Write a self-describing run artifact here: config snapshot +
     # resolved gates, platform/env fingerprint, JSONL metrics, telemetry
@@ -1541,17 +1539,15 @@ def _build_parser() -> argparse.ArgumentParser:
                         "off = registered defaults, or a table path; "
                         "explicit flags like -event-chunk still outrank "
                         "table entries")
-    p.add_argument("-profile", "--profile", action="store_true")
-    p.add_argument("-profile-dir", "--profile-dir", dest="profile_dir",
-                   default=d.profile_dir)
     p.add_argument("-trace", "--trace", default=d.trace,
                    help="write host-side phase/window spans as Chrome "
                         "trace-event JSON to this path")
     p.add_argument("-xprof", "--xprof", dest="xprof_dir",
                    default=d.xprof_dir,
                    help="wrap the run in a jax.profiler device trace "
-                        "(TensorBoard dir), with one TraceAnnotation per "
-                        "host span so device and host timelines align")
+                        "(TensorBoard/XProf dir): every host span is a "
+                        "TraceAnnotation and the ring engine's ops carry "
+                        "named scopes, so device and host timelines align")
     p.add_argument("-run-dir", "--run-dir", dest="run_dir",
                    default=d.run_dir,
                    help="write a self-describing run artifact (config, "

@@ -8,7 +8,6 @@ and optional profiling/checkpointing.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import os
 import time
@@ -290,7 +289,10 @@ def _run(cfg: Config, printer: ProgressPrinter,
     if not p1_interrupted:
         printer.section("Broadcast one message")
         if not resumed:
-            stepper.seed()
+            # On a fresh stepper the seed program's first call is its
+            # trace, lowering and compile or cache load.
+            with _trace.span("seed", cat="device"):
+                stepper.seed()
     target = cfg.coverage_target
     window_rounds = WINDOW_MS if cfg.effective_time_mode == "ticks" else 1
     # max_rounds caps simulated time at WINDOW granularity (both this loop
@@ -329,95 +331,98 @@ def _run(cfg: Config, printer: ProgressPrinter,
     serve_report = None
     hostloss_report = None
     interrupted = p1_interrupted
-    with _maybe_profile(cfg):
-        if p1_interrupted:
-            pass
-        elif cfg.supervise:
-            from gossip_simulator_tpu.distributed import supervisor as _sup
+    if p1_interrupted:
+        pass
+    elif cfg.supervise:
+        from gossip_simulator_tpu.distributed import supervisor as _sup
 
-            outcome = _sup.run_supervised(cfg, stepper, printer,
-                                          max_windows,
-                                          collect_rows=collect_rows,
-                                          run_id=run_id)
-            stepper = outcome.stepper
-            gossip_windows = outcome.windows
-            converged = outcome.converged
-            window_rows = outcome.rows
-            hostloss_report = outcome.report
-            interrupted = interrupted or outcome.interrupted
-            # A recovery rebuilds the stepper; device-recorded telemetry
-            # histories do not survive that (same rule as serve's
-            # reshards), so the artifact trajectory uses the
-            # host-collected rows.
-            telem = None
-        elif cfg.serve:
-            from gossip_simulator_tpu import serve as _serve
+        outcome = _sup.run_supervised(cfg, stepper, printer,
+                                      max_windows,
+                                      collect_rows=collect_rows,
+                                      run_id=run_id)
+        stepper = outcome.stepper
+        gossip_windows = outcome.windows
+        converged = outcome.converged
+        window_rows = outcome.rows
+        hostloss_report = outcome.report
+        interrupted = interrupted or outcome.interrupted
+        # A recovery rebuilds the stepper; device-recorded telemetry
+        # histories do not survive that (same rule as serve's
+        # reshards), so the artifact trajectory uses the
+        # host-collected rows.
+        telem = None
+    elif cfg.serve:
+        from gossip_simulator_tpu import serve as _serve
 
-            outcome = _serve.run_serve(cfg, stepper, printer, max_windows,
-                                       resume_window=resume_window,
-                                       collect_rows=collect_rows)
-            stepper = outcome.stepper
-            live_cfg = outcome.cfg
-            gossip_windows = outcome.windows
-            converged = outcome.converged
-            window_rows = outcome.rows
-            serve_report = outcome.report
-            interrupted = interrupted or outcome.interrupted
-            # Device-recorded telemetry histories do not survive a reshard
-            # (each incarnation starts its own); the artifact trajectory
-            # uses the host-collected rows instead (basis "windows" --
-            # row-identical to a twin's telemetry basis).
-            telem = None
-        elif fast:
-            with _trace.span("phase2.run_to_target", cat="phase") as sp:
-                stats = stepper.run_to_target()
+        outcome = _serve.run_serve(cfg, stepper, printer, max_windows,
+                                   resume_window=resume_window,
+                                   collect_rows=collect_rows)
+        stepper = outcome.stepper
+        live_cfg = outcome.cfg
+        gossip_windows = outcome.windows
+        converged = outcome.converged
+        window_rows = outcome.rows
+        serve_report = outcome.report
+        interrupted = interrupted or outcome.interrupted
+        # Device-recorded telemetry histories do not survive a reshard
+        # (each incarnation starts its own); the artifact trajectory
+        # uses the host-collected rows instead (basis "windows" --
+        # row-identical to a twin's telemetry basis).
+        telem = None
+    elif fast:
+        with _trace.span("phase2.run_to_target", cat="phase") as sp:
+            stats = stepper.run_to_target()
+            if sp is not None:
+                sp.update(rounds=int(stats.round),
+                          messages=int(stats.total_message),
+                          received=int(stats.total_received))
+        hist2 = telem.gossip_snapshot() if telem is not None else None
+        if hist2 and printer.observing:
+            _telemetry.replay_gossip(printer, hist2, n=cfg.n)
+        gossip_windows = (hist2["count"]
+                          if hist2 and not hist2["truncated"]
+                          else -(-stats.round // window_rounds))
+        converged = stats.coverage >= target
+        if _lifecycle.shutdown_requested():
+            interrupted = True
+    else:
+        while gossip_windows < max_windows:
+            with _trace.span("phase2.window", cat="window") as sp:
+                stats = stepper.gossip_window()
                 if sp is not None:
-                    sp.update(rounds=int(stats.round),
+                    sp.update(round=int(stats.round),
+                              received=int(stats.total_received),
                               messages=int(stats.total_message),
-                              received=int(stats.total_received))
-            hist2 = telem.gossip_snapshot() if telem is not None else None
-            if hist2 and printer.observing:
-                _telemetry.replay_gossip(printer, hist2, n=cfg.n)
-            gossip_windows = (hist2["count"]
-                              if hist2 and not hist2["truncated"]
-                              else -(-stats.round // window_rounds))
-            converged = stats.coverage >= target
+                              dropped=int(stats.mailbox_dropped))
+            gossip_windows += 1
+            if collect_rows:
+                window_rows.append((stats.round, stats.total_received,
+                                    stats.total_message,
+                                    stats.total_crashed,
+                                    stats.total_removed))
+            pct = stats.coverage * 100.0
+            printer.coverage_window(round(pct, 4), stepper.sim_time_ms())
+            if beacon is not None:
+                beacon.stamp(resume_window + gossip_windows)
+            # Offset by the restored window so post-resume snapshot
+            # numbers continue the sequence (checkpoint.latest is
+            # lexicographic).
+            ckpt.maybe_save(resume_window + gossip_windows, stats)
+            if stats.coverage >= target:
+                converged = True
+                break
+            if getattr(stepper, "exhausted", False):
+                break  # no messages in flight and nothing can change
             if _lifecycle.shutdown_requested():
                 interrupted = True
-        else:
-            while gossip_windows < max_windows:
-                with _trace.span("phase2.window", cat="window") as sp:
-                    stats = stepper.gossip_window()
-                    if sp is not None:
-                        sp.update(round=int(stats.round),
-                                  received=int(stats.total_received),
-                                  messages=int(stats.total_message),
-                                  dropped=int(stats.mailbox_dropped))
-                gossip_windows += 1
-                if collect_rows:
-                    window_rows.append((stats.round, stats.total_received,
-                                        stats.total_message,
-                                        stats.total_crashed,
-                                        stats.total_removed))
-                pct = stats.coverage * 100.0
-                printer.coverage_window(round(pct, 4), stepper.sim_time_ms())
-                if beacon is not None:
-                    beacon.stamp(resume_window + gossip_windows)
-                # Offset by the restored window so post-resume snapshot
-                # numbers continue the sequence (checkpoint.latest is
-                # lexicographic).
-                ckpt.maybe_save(resume_window + gossip_windows, stats)
-                if stats.coverage >= target:
-                    converged = True
-                    break
-                if getattr(stepper, "exhausted", False):
-                    break  # no messages in flight and nothing can change
-                if _lifecycle.shutdown_requested():
-                    interrupted = True
-                    break
+                break
     # A run interrupted mid-overlay has no epidemic state to read back.
     coverage_ms = 0.0 if p1_interrupted else stepper.sim_time_ms()
-    stats = Stats(n=cfg.n) if p1_interrupted else stepper.stats()
+    if p1_interrupted:
+        stats = Stats(n=cfg.n)
+    else:
+        with _trace.span("stats", cat="device"):
+            stats = stepper.stats()
     if serve_report is not None:
         stats.shed = serve_report["shed"]
     # A snapshot restored at/after the cap may already be at target.
@@ -674,13 +679,3 @@ class _Checkpointer:
             checkpoint.prune(self.cfg.checkpoint_dir, self.cfg.ckpt_keep,
                              prefix="overlay")
 
-
-@contextlib.contextmanager
-def _maybe_profile(cfg: Config):
-    if not cfg.profile:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(cfg.profile_dir):
-        yield

@@ -46,6 +46,16 @@ from gossip_simulator_tpu.utils import rng as _rng
 I32 = jnp.int32
 SEED_TICK = 0x7FFFFFFF  # reserved "tick" for the one-off seed draws (fold_in needs uint32)
 
+# Named scopes of the single-device phase-2 program (jax.named_scope: op
+# metadata only, the optimized HLO is unchanged).  A profile attributes
+# each device op to the innermost of these on its op_name path, else to
+# the scope of the loop running it (benchmark/scopes.py): COMBINE the
+# node-local tick (tick_core, counters), EMIT sender compaction, drop and
+# delay draws and the edge list, DELIVER the scatter-add into the delay
+# ring with the compaction chunk loop around it, POLL the per-window loop
+# predicate and telemetry row.
+COMBINE, EMIT, DELIVER, POLL = "combine", "emit", "deliver", "poll"
+
 
 def ring_depth(cfg: Config) -> int:
     """Delay ring slots: delays are clamped to [1, delayhigh) so `delayhigh`
@@ -357,9 +367,10 @@ def deposit_compact(cfg: Config, pending, friends, friend_cnt,
     if cfg.scenario_resolved.has_partitions:
         def body_p(_, carry):
             pending, remaining, blk = carry
-            dst, slots, valid, remaining, b = compact_gather(
-                cfg, friends, friend_cnt, dslot, delay_key, drop_key,
-                tick, remaining, cap)
+            with jax.named_scope(EMIT):
+                dst, slots, valid, remaining, b = compact_gather(
+                    cfg, friends, friend_cnt, dslot, delay_key, drop_key,
+                    tick, remaining, cap)
             return deposit_local(pending, dst, slots, valid,
                                  kernel=dkern), remaining, blk + b
 
@@ -369,9 +380,10 @@ def deposit_compact(cfg: Config, pending, friends, friend_cnt,
 
     def body(_, carry):
         pending, remaining = carry
-        dst, slots, valid, remaining, _ = compact_gather(
-            cfg, friends, friend_cnt, dslot, delay_key, drop_key, tick,
-            remaining, cap)
+        with jax.named_scope(EMIT):
+            dst, slots, valid, remaining, _ = compact_gather(
+                cfg, friends, friend_cnt, dslot, delay_key, drop_key, tick,
+                remaining, cap)
         return deposit_local(pending, dst, slots, valid,
                              kernel=dkern), remaining
 
@@ -436,60 +448,73 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
         target = int(math.ceil(cfg.coverage_target * cfg.n))
 
     def tick_fn(st: SimState, base_key: jax.Array) -> SimState:
-        st, dsc, dsr = apply_fault_window(
-            cfg, st, jnp.arange(st.received.shape[0], dtype=I32), base_key)
-        keys = tick_keys(base_key, st.tick)
+        with jax.named_scope(COMBINE):
+            st, dsc, dsr = apply_fault_window(
+                cfg, st, jnp.arange(st.received.shape[0], dtype=I32),
+                base_key)
+            keys = tick_keys(base_key, st.tick)
+            out = tick_core(cfg, st, keys)
+        stp, senders, dslot, (dm, dr, dc) = out[:4]
         if multi:
             # Always-dense delivery: the compact gather has no per-rumor
             # payload channel, and the multi configs are single-device
             # (validate) where the dense path is the proven form.
-            stp, senders, dslot, (dm, dr, dc), newbits = tick_core(
-                cfg, st, keys)
-            dst, slots, valid, blk = edges_from_senders(
-                cfg, stp.friends, stp.friend_cnt, senders, dslot,
-                keys["drop"], tick=st.tick)
-            if p2 == "pallas":
-                # Phase-2 megakernel: the counting add and the R-row
-                # rumor add land at the shared (slot, dst) cell in ONE
-                # joint pass (integer adds commute -> bit-identical to
-                # the sequential pair below).
-                from gossip_simulator_tpu.ops import pallas_megakernel \
-                    as mk
-                pending, prum = mk.fused_deposit_both(
-                    stp.pending, stp.pending_rumors, dst, slots, valid,
-                    newbits)
-                stp = stp._replace(pending_rumors=prum)
-            else:
-                pending = deposit_local(stp.pending, dst, slots, valid,
-                                        kernel=dkern)
-                stp = stp._replace(pending_rumors=deposit_rumors(
-                    stp.pending_rumors, dst, slots, valid, newbits,
-                    kernel=dkern))
-            hit = (stp.rumor_recv >= target) & (stp.rumor_done < 0)
-            stp = stp._replace(rumor_done=jnp.where(
-                hit, stp.tick, stp.rumor_done))
-        else:
-            stp, senders, dslot, (dm, dr, dc) = tick_core(cfg, st, keys)
-            if cfg.compact_resolved:
-                pending, blk = deposit_compact(
-                    cfg, stp.pending, stp.friends, stp.friend_cnt, senders,
-                    dslot, keys["delay"], keys["drop"], st.tick)
-            else:
+            newbits = out[4]
+            with jax.named_scope(EMIT):
                 dst, slots, valid, blk = edges_from_senders(
                     cfg, stp.friends, stp.friend_cnt, senders, dslot,
                     keys["drop"], tick=st.tick)
+            with jax.named_scope(DELIVER):
+                if p2 == "pallas":
+                    # Phase-2 megakernel: the counting add and the R-row
+                    # rumor add land at the shared (slot, dst) cell in ONE
+                    # joint pass (integer adds commute -> bit-identical to
+                    # the sequential pair below).
+                    from gossip_simulator_tpu.ops import pallas_megakernel \
+                        as mk
+                    pending, prum = mk.fused_deposit_both(
+                        stp.pending, stp.pending_rumors, dst, slots, valid,
+                        newbits)
+                    stp = stp._replace(pending_rumors=prum)
+                else:
+                    pending = deposit_local(stp.pending, dst, slots, valid,
+                                            kernel=dkern)
+                    stp = stp._replace(pending_rumors=deposit_rumors(
+                        stp.pending_rumors, dst, slots, valid, newbits,
+                        kernel=dkern))
+            with jax.named_scope(COMBINE):
+                hit = (stp.rumor_recv >= target) & (stp.rumor_done < 0)
+                stp = stp._replace(rumor_done=jnp.where(
+                    hit, stp.tick, stp.rumor_done))
+        elif cfg.compact_resolved:
+            # The chunk loop is DELIVER and opens EMIT inside for its
+            # gathers (deposit_compact): on a TPU the loop body also runs
+            # compiler-generated relayouts of the ring around the
+            # scatter-add, ops with no op_name of their own, which a
+            # profile reads under the loop's scope.
+            with jax.named_scope(DELIVER):
+                pending, blk = deposit_compact(
+                    cfg, stp.pending, stp.friends, stp.friend_cnt, senders,
+                    dslot, keys["delay"], keys["drop"], st.tick)
+        else:
+            with jax.named_scope(EMIT):
+                dst, slots, valid, blk = edges_from_senders(
+                    cfg, stp.friends, stp.friend_cnt, senders, dslot,
+                    keys["drop"], tick=st.tick)
+            with jax.named_scope(DELIVER):
                 pending = deposit_local(stp.pending, dst, slots, valid,
                                         kernel=dkern)
-        stp = stp._replace(
-            pending=pending,
-            total_message=msg64_add(stp.total_message, dm),
-            total_received=stp.total_received + dr,
-            total_crashed=stp.total_crashed + dc)
-        if cfg.scenario_resolved.active:
+        with jax.named_scope(COMBINE):
             stp = stp._replace(
-                scen_crashed=stp.scen_crashed + dsc,
-                scen_recovered=stp.scen_recovered + dsr,
-                part_dropped=stp.part_dropped + blk)
+                pending=pending,
+                total_message=msg64_add(stp.total_message, dm),
+                total_received=stp.total_received + dr,
+                total_crashed=stp.total_crashed + dc)
+            if cfg.scenario_resolved.active:
+                stp = stp._replace(
+                    scen_crashed=stp.scen_crashed + dsc,
+                    scen_recovered=stp.scen_recovered + dsr,
+                    part_dropped=stp.part_dropped + blk)
         return stp
 
     return tick_fn
@@ -842,21 +867,22 @@ def make_run_to_coverage_fn(cfg: Config, telemetry: bool = False):
     rumors = cfg.rumors
 
     def cond_live(s: SimState, target_count, until):
-        if multi:
-            # Every rumor must hit the target; lanes >= R are padding.
-            recv = jnp.min(s.rumor_recv[:rumors])
-        else:
-            recv = s.total_received
-        live = ((recv < target_count)
-                & (s.tick < max_steps) & (s.tick < until))
-        if check_in_flight:
-            # In-flight term (an O(d*n) emptiness test per window, not
-            # per tick): exit the device loop the moment the wave dies
-            # instead of spinning empty windows until the bounded-call
-            # budget lets the host notice -- parity with the event
-            # engine's cond (event.make_run_to_coverage_fn).
-            live = live & (in_flight(s) > 0)
-        return live
+        with jax.named_scope(POLL):
+            if multi:
+                # Every rumor must hit the target; lanes >= R are padding.
+                recv = jnp.min(s.rumor_recv[:rumors])
+            else:
+                recv = s.total_received
+            live = ((recv < target_count)
+                    & (s.tick < max_steps) & (s.tick < until))
+            if check_in_flight:
+                # In-flight term (an O(d*n) emptiness test per window, not
+                # per tick): exit the device loop the moment the wave dies
+                # instead of spinning empty windows until the bounded-call
+                # budget lets the host notice -- parity with the event
+                # engine's cond (event.make_run_to_coverage_fn).
+                live = live & (in_flight(s) > 0)
+            return live
 
     def run_window(s: SimState, base_key):
         # One window per iteration keeps the predicate check off the
@@ -883,9 +909,11 @@ def make_run_to_coverage_fn(cfg: Config, telemetry: bool = False):
             def body(carry):
                 s, h = carry
                 s = run_window(s, base_key)
-                row = telem.gossip_probe(
-                    s, sir, rumors=rumors if multi else 0)
-                return s, telem.record_window(h, row, st=s, spec=spatial)
+                with jax.named_scope(POLL):
+                    row = telem.gossip_probe(
+                        s, sir, rumors=rumors if multi else 0)
+                    return s, telem.record_window(h, row, st=s,
+                                                  spec=spatial)
 
             return jax.lax.while_loop(cond, body, (st, hist))
 

@@ -433,9 +433,12 @@ def _msg64_col(cols: np.ndarray) -> np.ndarray:
 
 class TelemetrySession:
     """Per-stepper holder: device histories for both phases plus the
-    wall-clock phase ledger.  The first-ever bounded device call of each
-    phase is tallied as `compile_s` (tracing + XLA compile dominate it;
-    subsequent calls reuse the executable), the rest as `execute_s`."""
+    wall-clock phase ledger.  `compile_s` is the host time of the dispatch
+    of each phase's first bounded device call -- the jitted call up to its
+    return: trace, lowering, XLA compile or persistent-cache load, enqueue
+    (a warm cache makes it mostly the first three, not a compile).
+    `execute_s` is the rest of that call, waiting on the device, and every
+    later call whole."""
 
     def __init__(self, cfg, n_shards: int = 1):
         self.cfg = cfg
@@ -453,14 +456,22 @@ class TelemetrySession:
     def add_phase(self, key: str, seconds: float) -> None:
         self.phases[key] = self.phases.get(key, 0.0) + seconds
 
-    def tally_gossip_call(self, seconds: float) -> None:
-        self.add_phase("compile_s" if self._gossip_calls == 0 else
-                       "execute_s", seconds)
+    def _tally_call(self, first: bool, dispatch_s: float,
+                    seconds: float) -> None:
+        if first:
+            self.add_phase("compile_s", dispatch_s)
+            seconds -= dispatch_s
+        self.add_phase("execute_s", seconds)
+
+    def tally_gossip_call(self, dispatch_s: float, seconds: float) -> None:
+        """One bounded phase-2 call: `seconds` whole, `dispatch_s` of it
+        until the jitted call returned."""
+        self._tally_call(self._gossip_calls == 0, dispatch_s, seconds)
         self._gossip_calls += 1
 
-    def tally_overlay_call(self, seconds: float) -> None:
-        self.add_phase("compile_s" if self._overlay_calls == 0 else
-                       "execute_s", seconds)
+    def tally_overlay_call(self, dispatch_s: float, seconds: float) -> None:
+        """One bounded phase-1 call (see tally_gossip_call)."""
+        self._tally_call(self._overlay_calls == 0, dispatch_s, seconds)
         self._overlay_calls += 1
 
     # --- phase-2 history ------------------------------------------------
@@ -483,12 +494,19 @@ class TelemetrySession:
 
     def gossip_snapshot(self) -> Optional[dict]:
         if self._gossip_fetched is None and self._gossip is not None:
-            import time
-
-            t0 = time.perf_counter()
-            self._gossip_fetched = fetch_history(self._gossip)
-            self.add_phase("fetch_s", time.perf_counter() - t0)
+            self._gossip_fetched = self._fetch(self._gossip)
         return self._gossip_fetched
+
+    def _fetch(self, hist) -> Optional[dict]:
+        import time
+
+        from gossip_simulator_tpu.utils import trace as _trace
+
+        t0 = time.perf_counter()
+        with _trace.span("telemetry.fetch", cat="device"):
+            out = fetch_history(hist)
+        self.add_phase("fetch_s", time.perf_counter() - t0)
+        return out
 
     # --- phase-1 history ------------------------------------------------
     def begin_overlay(self, cap: int) -> History:
@@ -508,11 +526,7 @@ class TelemetrySession:
     def overlay_snapshot(self) -> Optional[dict]:
         if self._overlay_fetched is None:
             if self._overlay is not None:
-                import time
-
-                t0 = time.perf_counter()
-                self._overlay_fetched = fetch_history(self._overlay)
-                self.add_phase("fetch_s", time.perf_counter() - t0)
+                self._overlay_fetched = self._fetch(self._overlay)
             elif self._overlay_host_rows:
                 self._overlay_fetched = host_history(self._overlay_host_rows)
         return self._overlay_fetched

@@ -3,22 +3,27 @@
 Every cross-revision perf claim so far (PR 2's exchange overhead, PR 3's
 phase-1 floors, PR 6's kernel parity) was established by hand-run A/B twins
 and hand-diffed BENCH_SELF rows.  This module records the same phase
-boundaries mechanically: named spans (compile, phase-1 rounds, phase-2
-windows, checkpoint save/load, sharded exchange, bench captures) with
-counter payloads (messages, mail high-water, drops) as span args, emitted
-as Chrome trace-event JSON (`chrome://tracing` / Perfetto "X" complete
-events) behind `-trace PATH`.
+boundaries mechanically: named spans (phase-1 rounds, phase-2 windows and
+bounded calls, checkpoint save/load, sharded exchange, bench captures) with
+counter payloads (messages, mail high-water, drops) as span args.
 
-`-xprof DIR` additionally wraps the run in ``jax.profiler.trace`` and
-enters a ``jax.profiler.TraceAnnotation`` per span, so the device timeline
-in TensorBoard lines up with the host spans recorded here.
+One recorder, two exporters on one clock:
+
+* every span is a ``jax.profiler.TraceAnnotation`` carrying its category
+  as the ``cat`` stat (plus its entry args), whether or not a tracer is
+  active -- any profiler session sees the program's spans on the device
+  trace's clock: `-xprof DIR`, a benchmark's traced run, an external
+  XProf capture;
+* `-trace PATH` writes them as Chrome trace-event JSON (`chrome://tracing`
+  / Perfetto "X" complete events) stamped on the same wall clock: the
+  document's ``clock_origin_unix_ns`` plus ``ts`` x 1000 is Unix ns, the
+  value an xplane gives as ``profile_start_time`` + an event's start.
 
 Instrumentation sites use the module-level ``span()`` / ``instant()``
-helpers, which are strict no-ops while no tracer is active -- backends,
-checkpoint and bench never need cfg plumbing, and a run without `-trace`
-executes zero extra work on the hot path (one None check per span site,
-all of which sit on host-side per-call/per-window boundaries, never inside
-jitted code).
+helpers.  With no active tracer a span is the annotation alone (one
+enter/exit, ~1 us, a no-op unless a profiler is running) and yields None,
+so call sites compute no counters and force no syncs.  All sites sit on
+host-side per-call/per-window boundaries, never inside jitted code.
 """
 
 from __future__ import annotations
@@ -31,28 +36,36 @@ import time
 from typing import Optional
 
 
+def _annotation(name: str, cat: str, args: dict):
+    from jax import profiler
+
+    return profiler.TraceAnnotation(name, cat=cat, **args)
+
+
 class Tracer:
     """Collects Chrome trace-event "X" (complete) events host-side.
 
-    Timestamps are microseconds from the tracer's construction
-    (perf_counter based -- monotonic, sub-us resolution); one tracer spans
-    one run (or one bench suite).  Thread-safe appends: the sharded
-    backend and bench are single-threaded today, but the lock keeps the
-    recorder safe if a callback ever fires from a jax runtime thread.
+    Timestamps are microseconds from the tracer's construction on the wall
+    clock (``time.time_ns``), the clock the JAX profiler stamps host events
+    with; ``clock_origin_unix_ns`` in the document's metadata is that
+    origin.  One tracer spans one run (or one bench suite).  Thread-safe
+    appends: the sharded backend and bench are single-threaded today, but
+    the lock keeps the recorder safe if a callback ever fires from a jax
+    runtime thread.
     """
 
     def __init__(self, path: str = "", xprof_dir: str = ""):
         self.path = path
         self.xprof_dir = xprof_dir
         self.events: list[dict] = []
-        self._t0 = time.perf_counter()
+        self.origin_ns = time.time_ns()
         self._lock = threading.Lock()
         self._pid = os.getpid()
         self._xprof_cm = None
 
     # --- clock ----------------------------------------------------------
     def now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return (time.time_ns() - self.origin_ns) / 1e3
 
     # --- recording ------------------------------------------------------
     @contextlib.contextmanager
@@ -67,13 +80,10 @@ class Tracer:
                 stats = stepper.gossip_window()
                 sp["messages"] = stats.total_message
         """
-        ann = self._annotation(name)
+        ann = _annotation(name, cat, args)
         t0 = self.now_us()
         try:
-            if ann is not None:
-                with ann:
-                    yield args
-            else:
+            with ann:
                 yield args
         finally:
             ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
@@ -95,16 +105,6 @@ class Tracer:
             self.events.append(ev)
 
     # --- xprof (device timeline) ----------------------------------------
-    def _annotation(self, name: str):
-        if not self.xprof_dir:
-            return None
-        try:
-            import jax
-
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:
-            return None
-
     def start(self) -> None:
         """Begin the optional device-side profile (`-xprof DIR`)."""
         if self.xprof_dir and self._xprof_cm is None:
@@ -122,10 +122,9 @@ class Tracer:
     def to_json(self, metadata: Optional[dict] = None) -> dict:
         with self._lock:
             events = list(self.events)
-        doc: dict = {"traceEvents": events, "displayTimeUnit": "ms"}
-        if metadata:
-            doc["metadata"] = metadata
-        return doc
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "metadata": {"clock_origin_unix_ns": self.origin_ns,
+                             **(metadata or {})}}
 
     def write(self, path: str = "", metadata: Optional[dict] = None) -> str:
         """Write the trace file (one JSON document, Chrome/Perfetto
@@ -166,29 +165,31 @@ def active() -> Optional[Tracer]:
     return _ACTIVE
 
 
-class _NullContext:
-    """Inactive-tracer span: yields None (callers guard counter updates
-    with `if sp:`) and costs one shared-instance enter/exit."""
+class _AnnotationOnly:
+    """Inactive-tracer span: the profiler annotation alone.  Yields None
+    (callers guard counter updates with `if sp:`)."""
 
-    __slots__ = ()
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, cat: str, args: dict):
+        self._ann = _annotation(name, cat, args)
 
     def __enter__(self):
+        self._ann.__enter__()
         return None
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         return False
 
 
-_NULL = _NullContext()
-
-
 def span(name: str, cat: str = "host", **args):
-    """Context manager: a timed span on the active tracer, or a no-op.
-    Yields the span's args dict (add counters before exit), or None when
-    tracing is off."""
+    """Context manager: a profiler annotation, and a timed span on the
+    active tracer.  Yields the span's args dict (add counters before
+    exit), or None when no tracer is active."""
     t = _ACTIVE
     if t is None:
-        return _NULL
+        return _AnnotationOnly(name, cat, args)
     return t.span(name, cat=cat, **args)
 
 

@@ -145,15 +145,119 @@ def test_trace_json_structure(tmp_path):
     _run(tmp_path, "tr", run_dir=rdir)
     doc = json.load(open(rdir / "trace.json"))
     assert doc["displayTimeUnit"] == "ms"
+    assert doc["metadata"]["clock_origin_unix_ns"] > 0
     events = doc["traceEvents"]
     names = {e["name"] for e in events}
     assert "init" in names
-    assert {"phase2.run_to_target", "phase2.compile+run"} <= names
+    assert {"phase2.run_to_target", "phase2.dispatch", "phase2.sync",
+            "seed", "stats", "telemetry.fetch"} <= names
     for e in events:
         if e["ph"] == "X":
             assert e["dur"] >= 0 and "ts" in e and "cat" in e
     run = next(e for e in events if e["name"] == "phase2.run_to_target")
     assert run["args"]["messages"] > 0
+    dispatch = [e for e in events if e["name"] == "phase2.dispatch"]
+    sync = [e for e in events if e["name"] == "phase2.sync"]
+    assert [e["args"]["call"] for e in dispatch] == list(range(len(sync)))
+    assert sync[-1]["args"]["received"] > 0
+    # One bounded call: dispatch, then the sync that waits for it, both
+    # inside run_to_target.
+    for d, s in zip(dispatch, sync):
+        assert run["ts"] <= d["ts"] <= d["ts"] + d["dur"] <= s["ts"]
+        assert s["ts"] + s["dur"] <= run["ts"] + run["dur"]
+
+
+def _xplane(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                       recursive=True)
+    return ProfileData.from_file(path)
+
+
+def _host_events(pd):
+    """(name, start_ns, stats) of the profile's host events, without the
+    Python tracer's function events."""
+    return [(ev.name, ev.start_ns, dict(ev.stats))
+            for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for ev in ln.events
+            if not ev.name.startswith("$")]
+
+
+def test_span_is_a_profiler_annotation_without_tracer(tmp_path):
+    """With no active Tracer a program span still lands in any profiler
+    session, with its category and entry args as stats, and yields None."""
+    import jax
+
+    assert trace.active() is None
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.span("probe.span", cat="test", call=2) as sp:
+            assert sp is None
+    [(_, _, stats)] = [e for e in _host_events(_xplane(tmp_path))
+                       if e[0] == "probe.span"]
+    assert stats == {"cat": "test", "call": 2}
+
+
+def test_program_spans_reach_a_profiler_session(tmp_path):
+    """A plain CLI job under an outside profiler (no -trace, no -xprof):
+    the program's boundaries are all on the host plane as `cat` spans."""
+    import jax
+
+    cfg = Config(**BASE, engine="ring").validate()
+    with jax.profiler.trace(str(tmp_path)):
+        run_simulation(cfg, printer=ProgressPrinter(enabled=False))
+    cats = {name: stats["cat"] for name, _, stats in
+            _host_events(_xplane(tmp_path)) if "cat" in stats}
+    assert cats == {"init": "phase", "phase1.quiesce": "phase",
+                    "seed": "device", "phase2.run_to_target": "phase",
+                    "phase2.dispatch": "device", "phase2.sync": "device",
+                    "telemetry.fetch": "device", "stats": "device"}
+
+
+def test_trace_json_and_xprof_share_a_clock(tmp_path):
+    """-trace and -xprof together: phase2.run_to_target's start in the JSON
+    (clock_origin_unix_ns + ts) and in the xplane (profile_start_time +
+    start_ns) is the same instant, within 1 ms."""
+    xdir = tmp_path / "xprof"
+    cfg = Config(n=2000, backend="jax", graph="kout", fanout=6, seed=4,
+                 coverage_target=0.9, trace=str(tmp_path / "t.json"),
+                 xprof_dir=str(xdir)).validate()
+    run_simulation(cfg, printer=ProgressPrinter(enabled=False))
+    doc = json.load(open(tmp_path / "t.json"))
+    [run] = [e for e in doc["traceEvents"]
+             if e["name"] == "phase2.run_to_target"]
+    json_ns = doc["metadata"]["clock_origin_unix_ns"] + run["ts"] * 1e3
+    pd = _xplane(xdir)
+    env = next(p for p in pd.planes if p.name == "Task Environment")
+    start = dict(env.stats)["profile_start_time"]
+    [xp] = [t for name, t, _ in _host_events(pd)
+            if name == "phase2.run_to_target"]
+    assert abs(start + xp - json_ns) <= 1e6
+
+
+@pytest.mark.parametrize("telemetry", ["on", "off"])
+def test_ring_engine_named_scopes(telemetry):
+    """The ring engine's compiled run program carries the four phase-2
+    scope names on its op_name paths (profiles attribute device time by
+    them); the scopes are metadata only."""
+    import re
+
+    from gossip_simulator_tpu.backends import make_stepper
+
+    cfg = Config(n=2000, backend="jax", graph="kout", fanout=6, seed=3,
+                 engine="ring", telemetry=telemetry).validate()
+    st = make_stepper(cfg)
+    st.init()
+    st.overlay_window()
+    args = (st.state, st.key, np.int32(1800), np.int32(64))
+    if st._telem is not None:
+        args += (st._telem.begin_gossip(),)
+    txt = st._run_fn.lower(*args).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', txt)
+    for scope in ("combine", "emit", "deliver", "poll"):
+        assert any(f"/{scope}/" in p for p in paths), scope
 
 
 def test_tracer_spans_nest_and_null_path():

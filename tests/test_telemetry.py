@@ -190,3 +190,18 @@ def test_telemetry_off_quiet_run_unchanged(tmp_path):
                            printer=ProgressPrinter(enabled=False))
     assert r_on.stats == r_off.stats
     assert r_on.gossip_windows == r_off.gossip_windows
+
+
+def test_compile_s_is_first_dispatch():
+    """compile_s is the host time of the first bounded call's dispatch
+    (trace, lowering, compile or cache load, enqueue); the rest of that
+    call and every later call are execute_s -- per phase."""
+    from gossip_simulator_tpu.utils.telemetry import TelemetrySession
+
+    s = TelemetrySession(Config(n=100))
+    s.tally_gossip_call(0.75, 1.0)
+    s.tally_gossip_call(0.01, 0.5)
+    s.tally_overlay_call(0.25, 0.5)
+    s.tally_overlay_call(0.02, 0.25)
+    assert s.phases == pytest.approx({"compile_s": 1.0,
+                                      "execute_s": 0.25 + 0.5 + 0.25 + 0.25})
