@@ -398,6 +398,11 @@ class JaxStepper(Stepper):
         # COPY (np.array), never view: see overlay_state_pytree's note on
         # the CPU zero-copy + donated-buffer-reuse aliasing.
         tree = {k: np.array(v) for k, v in self.state._asdict().items()}
+        if "pending" in tree:
+            # The ring engine's flat delay ring is saved in its logical
+            # (d, n) shape, the sharded backend's, so snapshots move
+            # between the two (epidemic.ring_row).
+            tree["pending"] = tree["pending"].reshape(-1, self.cfg.n)
         if "mail_ids" in tree:
             # Record the mail-ring geometry so a future build whose AUTO
             # slot-cap/chunk sizing differs can repack instead of rejecting
@@ -434,6 +439,8 @@ class JaxStepper(Stepper):
             cls = PushSumState
         else:
             cls = EventState if cfg.engine_resolved == "event" else SimState
+        if cls is SimState:
+            tree["pending"] = np.reshape(tree["pending"], -1)
         # jax.numpy.array (device COPY), not asarray: on the CPU platform
         # asarray of a host array can be zero-copy, and these leaves feed
         # straight into DONATING jitted fns -- XLA then reuses a buffer it

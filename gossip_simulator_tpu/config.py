@@ -1242,6 +1242,17 @@ class Config:
                     "-resume requires backend=jax or sharded")
         if self.fanout >= self.n:
             raise ValueError(f"fanout ({self.fanout}) must be < n ({self.n})")
+        if self.backend == "jax" and self.engine_resolved == "ring":
+            # The single-device ring is one flat int32[d * n] array whose
+            # int32 scatter index uses d * n as its drop sentinel
+            # (epidemic.deposit_local); d is epidemic.ring_depth.
+            d = self.delayhigh if self.effective_time_mode == "ticks" else 2
+            if d * self.n >= 2**31 - 1:
+                raise ValueError(
+                    f"the ring engine's delay ring ({d} slots from "
+                    f"-delayhigh, times -n {self.n}) holds {d * self.n} "
+                    f"cells, over the int32 index bound {2**31 - 1}; lower "
+                    "-delayhigh or -n, or run -backend sharded")
         return self
 
     def replace(self, **kw) -> "Config":

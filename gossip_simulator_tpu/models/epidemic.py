@@ -12,8 +12,9 @@ Time semantics ("ticks" mode): 1 tick == 1 simulated ms.  Every broadcast
 draws ONE shared delay uniform in [delaylow, delayhigh) ticks -- exactly the
 reference's RandomNetworkDelay applied once per broadcast goroutine
 (simulator.go:141-142) -- and each link send has an independent drop draw
-(simulator.go:144).  Messages sit in a ``pending[d, n]`` ring buffer of
-arrival *counts* so duplicate deliveries are counted like the reference's
+(simulator.go:144).  Messages sit in a ``pending`` ring buffer of d slots of
+n arrival *counts* (flat ``[d * n]`` single-device, ``[d, n_local]`` per
+shard: ring_row) so duplicate deliveries are counted like the reference's
 TotalMessage (simulator.go:111) while infection stays an idempotent OR.
 
 "rounds" mode is the classic synchronous-epidemic accounting: every hop takes
@@ -103,8 +104,12 @@ def pack_rumor_bits(bits: jnp.ndarray, w: int) -> jnp.ndarray:
 
 def init_state(cfg: Config, friends: jnp.ndarray, friend_cnt: jnp.ndarray,
                n_local: int | None = None, n_shards: int = 1) -> SimState:
+    """SimState for the whole node axis, or one shard's slice of it when
+    the sharded backend passes `n_local`.  The single-device delay ring is
+    flat and the per-shard one 2-D (ring_row)."""
     n = n_local if n_local is not None else cfg.n
     d = ring_depth(cfg)
+    ring = (d * n,) if n_local is None else (d, n)
     d_rb = d if cfg.protocol == "sir" else 1
     z = lambda: jnp.zeros((), I32)
     pending_rumors, rumor_words, rumor_recv, rumor_done = init_rumor_leaves(
@@ -115,7 +120,7 @@ def init_state(cfg: Config, friends: jnp.ndarray, friend_cnt: jnp.ndarray,
         removed=jnp.zeros((n,), bool),
         friends=friends,
         friend_cnt=friend_cnt,
-        pending=jnp.zeros((d, n), I32),
+        pending=jnp.zeros(ring, I32),
         rebroadcast=jnp.zeros((d_rb, n), bool),
         tick=z(), total_message=msg64_zero(), total_received=z(),
         total_crashed=z(),
@@ -141,6 +146,23 @@ def row_slot(cfg: Config, delay_key, tick, rows):
     delay = _rng.row_uniform_delay(delay_key, cfg.delaylow, cfg.delayhigh,
                                    rows)
     return ((tick + delay) % d).astype(I32)
+
+
+def ring_row(pending, slot, n: int):
+    """Ring slot `slot`'s n arrival counts.  The single-device ring is flat,
+    int32[d * n] with slot s at [s * n, (s + 1) * n) (deposit_local says
+    why); the sharded engine's per-shard ring is int32[d, n_local]."""
+    if pending.ndim == 1:
+        return jax.lax.dynamic_slice(pending, (slot * n,), (n,))
+    return pending[slot]
+
+
+def ring_clear(pending, slot, n: int):
+    """`pending` with slot `slot` zeroed (either form, see ring_row)."""
+    if pending.ndim == 1:
+        return jax.lax.dynamic_update_slice(
+            pending, jnp.zeros((n,), pending.dtype), (slot * n,))
+    return pending.at[slot].set(0)
 
 
 def tick_keys(base_key: jax.Array, tick, shard: jax.Array | int | None = None):
@@ -185,8 +207,8 @@ def tick_core(cfg: Config, st: SimState, keys: dict):
     ids = jnp.arange(n, dtype=I32)
 
     slot = st.tick % d
-    arrivals = st.pending[slot]
-    pending = st.pending.at[slot].set(0)
+    arrivals = ring_row(st.pending, slot, n)
+    pending = ring_clear(st.pending, slot, n)
     counted = jnp.where(st.crashed, 0, arrivals)  # black-hole, uncounted
     d_message = counted.sum(dtype=I32)
     has = counted > 0
@@ -371,8 +393,8 @@ def deposit_compact(cfg: Config, pending, friends, friend_cnt,
                 dst, slots, valid, remaining, b = compact_gather(
                     cfg, friends, friend_cnt, dslot, delay_key, drop_key,
                     tick, remaining, cap)
-            return deposit_local(pending, dst, slots, valid,
-                                 kernel=dkern), remaining, blk + b
+            return deposit_local(pending, dst, slots, valid, kernel=dkern,
+                                 n=n), remaining, blk + b
 
         pending, _, blk = jax.lax.fori_loop(
             0, chunks, body_p, (pending, senders, jnp.zeros((), I32)))
@@ -384,30 +406,43 @@ def deposit_compact(cfg: Config, pending, friends, friend_cnt,
             dst, slots, valid, remaining, _ = compact_gather(
                 cfg, friends, friend_cnt, dslot, delay_key, drop_key, tick,
                 remaining, cap)
-        return deposit_local(pending, dst, slots, valid,
-                             kernel=dkern), remaining
+        return deposit_local(pending, dst, slots, valid, kernel=dkern,
+                             n=n), remaining
 
     pending, _ = jax.lax.fori_loop(0, chunks, body, (pending, senders))
     return pending, 0
 
 
-def deposit_local(pending, dst_local, slots, valid, kernel="xla"):
+def deposit_local(pending, dst_local, slots, valid, kernel="xla", n=None):
     """Scatter arrivals into the pending ring (idempotent counting add;
     duplicates accumulate like the reference's per-message channel sends).
+    A flat ring (ring_row) needs its row length `n`.
 
-    NOTE: keep the 2-D scatter with per-axis OOB drop.  A flat 1-D variant
-    (index = slot * n + dst, invalid -> d*n) is ~5x faster in isolation but
-    leans on the OOB-drop of a flattened index inside the jitted tick;
-    chip_smoke.py's pinned totals are the check that drops still apply.
+    NOTE: keep the single-device ring flat.  The TPU compiler lowers a
+    scatter-add only onto a linear buffer, so on a 2-D [d, n] ring it
+    copied the whole ring out to a flat buffer and back around every call
+    -- once per compaction chunk, most of phase 2's device time.  The flat
+    scatter runs in place.  An invalid lane indexes d * n, outside the ring
+    for every slot, so mode="drop" discards it explicitly; the 2-D form's
+    sentinel dst = n would flatten to node 0 of the NEXT slot.
+    chip_smoke.py's pinned totals check that the drop applies on the chip.
     kernel="pallas" routes to the fused serial add
     (ops/pallas_deliver.fused_deposit_add) whose in-range check replaces
     the scatter's OOB-drop explicitly -- integer adds commute, so it is
     bit-identical."""
-    n = pending.shape[1]
-    dst = jnp.where(valid, dst_local, n)  # out of bounds -> mode="drop"
+    flat = pending.ndim == 1
+    if not flat:
+        n = pending.shape[1]
     if kernel == "pallas":
         from gossip_simulator_tpu.ops import pallas_deliver
-        return pallas_deliver.fused_deposit_add(pending, slots, dst)
+        dst = jnp.where(valid, dst_local, n)
+        out = pallas_deliver.fused_deposit_add(pending.reshape(-1, n),
+                                               slots, dst)
+        return out.reshape(pending.shape)
+    if flat:
+        idx = jnp.where(valid, slots * n + dst_local, pending.shape[0])
+        return pending.at[idx].add(1, mode="drop")
+    dst = jnp.where(valid, dst_local, n)  # out of bounds -> mode="drop"
     return pending.at[slots, dst].add(1, mode="drop")
 
 
@@ -415,12 +450,11 @@ def deposit_rumors(pending_rumors, dst_local, slots, valid, newbits,
                    kernel="xla"):
     """Multi-rumor companion to deposit_local: each kept edge adds its
     sender's NEW rumor bits (one-hot int rows) into the destination's
-    (slot, dst) per-rumor lane.  Same 2-D leading-index scatter form as
-    deposit_local (see the NOTE there); the R axis rides as the
-    scatter's trailing window dimension.  kernel="pallas" applies the
-    whole R-row add in-register at the shared (slot, dst) cell
-    (fused_deposit_rows) -- the multi-rumor combine rides the fused pass
-    for free."""
+    (slot, dst) per-rumor lane: a 2-D leading-index scatter into the
+    [d, n, R] ring, the R axis riding as the scatter's trailing window
+    dimension.  kernel="pallas" applies the whole R-row add in-register
+    at the shared (slot, dst) cell (fused_deposit_rows) -- the multi-rumor
+    combine rides the fused pass for free."""
     n, r = newbits.shape
     k = dst_local.shape[0] // n
     vals = jnp.broadcast_to(newbits[:, None, :].astype(I32),
@@ -455,6 +489,7 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
             keys = tick_keys(base_key, st.tick)
             out = tick_core(cfg, st, keys)
         stp, senders, dslot, (dm, dr, dc) = out[:4]
+        n = stp.received.shape[0]
         if multi:
             # Always-dense delivery: the compact gather has no per-rumor
             # payload channel, and the multi configs are single-device
@@ -473,12 +508,13 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
                     from gossip_simulator_tpu.ops import pallas_megakernel \
                         as mk
                     pending, prum = mk.fused_deposit_both(
-                        stp.pending, stp.pending_rumors, dst, slots, valid,
-                        newbits)
+                        stp.pending.reshape(-1, n), stp.pending_rumors, dst,
+                        slots, valid, newbits)
+                    pending = pending.reshape(stp.pending.shape)
                     stp = stp._replace(pending_rumors=prum)
                 else:
                     pending = deposit_local(stp.pending, dst, slots, valid,
-                                            kernel=dkern)
+                                            kernel=dkern, n=n)
                     stp = stp._replace(pending_rumors=deposit_rumors(
                         stp.pending_rumors, dst, slots, valid, newbits,
                         kernel=dkern))
@@ -488,10 +524,10 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
                     hit, stp.tick, stp.rumor_done))
         elif cfg.compact_resolved:
             # The chunk loop is DELIVER and opens EMIT inside for its
-            # gathers (deposit_compact): on a TPU the loop body also runs
-            # compiler-generated relayouts of the ring around the
-            # scatter-add, ops with no op_name of their own, which a
-            # profile reads under the loop's scope.
+            # gathers (deposit_compact): the loop's own bookkeeping and
+            # any op the compiler makes there carry no op_name, and a
+            # profile reads them under the loop's scope.  The scatter-add
+            # writes the flat ring in place (deposit_local's NOTE).
             with jax.named_scope(DELIVER):
                 pending, blk = deposit_compact(
                     cfg, stp.pending, stp.friends, stp.friend_cnt, senders,
@@ -503,7 +539,7 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
                     keys["drop"], tick=st.tick)
             with jax.named_scope(DELIVER):
                 pending = deposit_local(stp.pending, dst, slots, valid,
-                                        kernel=dkern)
+                                        kernel=dkern, n=n)
         with jax.named_scope(COMBINE):
             stp = stp._replace(
                 pending=pending,
@@ -557,7 +593,7 @@ def make_seed_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
             dst, slots, valid, blk = edges_from_senders(
                 cfg, st.friends, st.friend_cnt, is_src, dslot, kp,
                 tick=st.tick)
-            pending = deposit_local(st.pending, dst, slots, valid)
+            pending = deposit_local(st.pending, dst, slots, valid, n=n)
             pending_r = deposit_rumors(
                 st.pending_rumors, dst, slots, valid,
                 unpack_rumor_bits(delta, r_total))
@@ -593,7 +629,7 @@ def make_seed_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
             tick=st.tick)
         if cfg.scenario_resolved.has_partitions:
             st = st._replace(part_dropped=st.part_dropped + blk)
-        pending = deposit_local(st.pending, dst, slots, valid)
+        pending = deposit_local(st.pending, dst, slots, valid, n=n)
         rb = st.rebroadcast
         if cfg.protocol == "sir":
             # The seed is a sender like any other: removal draw decides
@@ -783,10 +819,11 @@ def make_heal_fn(cfg: Config):
             dslot = ((st.tick + delay) % d).astype(I32)
         slots = jnp.broadcast_to(dslot[:, None], (n, k)).reshape(-1)
         dst = jnp.where(resend, friends, -1).reshape(-1)
-        pending = deposit_local(st.pending, dst, slots, resend.reshape(-1))
+        pending = deposit_local(st.pending, dst, slots, resend.reshape(-1),
+                                n=n)
         # Rejoin pull responses deliver to the puller's OWN row.
         pdst = jnp.broadcast_to(ids[:, None], (n, k)).reshape(-1)
-        pending = deposit_local(pending, pdst, slots, pull.reshape(-1))
+        pending = deposit_local(pending, pdst, slots, pull.reshape(-1), n=n)
         return st._replace(
             friends=friends, pending=pending,
             down_since=jnp.where(clear, -1, st.down_since),

@@ -181,6 +181,55 @@ def test_sharded_ring_resume_reproduces_trajectory(tmp_path):
         assert s2.gossip_window() == want
 
 
+@pytest.mark.parametrize("first", ["jax", "sharded"])
+def test_ring_snapshot_crosses_backends_mid_phase2(tmp_path, first):
+    """The single-device ring engine keeps its delay ring flat but
+    snapshots it as (d, n), the sharded ring's shape.  A mid-phase-2
+    snapshot of either backend restores into the other (S = 1), whose own
+    snapshot restores back, and the run finishes Stats-exact against the
+    uninterrupted one."""
+    from gossip_simulator_tpu.backends.sharded import ShardedStepper
+    from gossip_simulator_tpu.models import epidemic
+
+    base = dict(n=2000, graph="kout", fanout=6, seed=3, crashrate=0.0,
+                engine="ring", progress=False)
+
+    def stepper(backend, **kw):
+        cfg = Config(**base, backend=backend, **kw).validate()
+        s = (JaxStepper(cfg) if backend == "jax"
+             else ShardedStepper(cfg, n_devices=1))
+        s.init()
+        return s
+
+    resume = dict(resume=True, checkpoint_dir=str(tmp_path))
+    other = "sharded" if first == "jax" else "jax"
+    s = stepper(first)
+    if first == "jax":
+        assert s.state.pending.shape == (20 * 2000,)
+    s.seed()
+    s.gossip_window()
+    s.gossip_window()
+    mid = s.stats()
+    tree, _ = checkpoint.load(
+        checkpoint.save(str(tmp_path), 2, s.state_pytree(), mid))
+    assert tree["pending"].shape == (epidemic.ring_depth(s.cfg), 2000)
+    assert tree["pending"].any()  # messages in flight
+    reference = []
+    while not reference or reference[-1].coverage < 0.99:
+        reference.append(s.gossip_window())
+
+    t = stepper(other, **resume)
+    t.load_state_pytree(dict(tree))
+    assert t.stats() == mid
+    back_tree = t.state_pytree()
+    np.testing.assert_array_equal(back_tree["pending"], tree["pending"])
+    back = stepper(first, **resume)
+    back.load_state_pytree(back_tree)
+    assert back.stats() == mid
+    for want in reference:
+        assert back.gossip_window() == want
+
+
 def test_sharded_resume_repacks_mail_geometry(tmp_path):
     """A sharded snapshot written under one -event-chunk restores under a
     different one via the per-shard slot repack."""

@@ -10,6 +10,9 @@ step that does not fit HBM, a mesh program without its collective.
   compiler's message, so the PR that repairs it must flip the mark.
 * The jitted single-device SI window step at n = 10M, its
   memory_analysis() bytes under the chip's 16 GiB.
+* The ring engine's phase-2 run program at 1M rows: the scatter-add
+  writes the flat delay ring in place, with no copy of the ring to
+  another layout and back.
 * The S=4 sharded window step on a mesh over the described devices, with
   the all-to-all in its compiled text.
 
@@ -142,6 +145,50 @@ def test_si_window_step_10m_fits_one_chip(one_chip, no_cache):
     peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert 0 < peak < HBM_BYTES, peak
+
+
+def _ring_sized(hlo_text, ring):
+    """(op, name, count) for every while loop and dynamic-update-slice in
+    the optimized HLO, with the number of ring-sized int32 arrays in its
+    output (a loop's output is its whole carry)."""
+    import re
+
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.+?) "
+                         r"(while|dynamic-update-slice)\(", hlo_text, re.M):
+        dims = re.findall(r"s32\[([\d,]+)\]", m.group(2))
+        count = sum(int(np.prod([int(x) for x in d.split(",")])) == ring
+                    for d in dims)
+        out.append((m.group(3), m.group(1), count))
+    return out
+
+
+def test_ring_run_scatters_into_the_ring_in_place(one_chip, no_cache):
+    """A 2-D [d, n] ring made the compiler copy the whole ring to a flat
+    buffer and back around the scatter-add in every compaction chunk: two
+    copy loops that carry the ring twice and a second ring-sized
+    dynamic-update-slice.  The flat ring leaves one ring per loop carry
+    and one ring-sized dynamic-update-slice, the slot clear."""
+    from gossip_simulator_tpu.models import epidemic, graphs
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    cfg = parse_args(["-n", str(ROWS), "-backend", "jax", "-engine", "ring",
+                      *SI_FLAGS])
+    ring = epidemic.ring_depth(cfg) * ROWS
+    state = jax.eval_shape(lambda: epidemic.init_state(
+        cfg, *graphs.generate(cfg, graphs.graph_key(cfg))))
+    key = jax.eval_shape(lambda: _rng.base_key(cfg.seed))
+    count = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    compiled = epidemic.make_run_to_coverage_fn(cfg).lower(
+        _on(state, one_chip), _on(key, one_chip), count, count).compile()
+    ops = _ring_sized(compiled.as_text(), ring)
+    loops = [(name, c) for op, name, c in ops if op == "while" and c]
+    assert loops and all(c == 1 for _, c in loops), loops
+    updates = [name for op, name, c in ops
+               if op == "dynamic-update-slice" and c]
+    assert len(updates) == 1, updates
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * ring, temp
 
 
 def test_sharded_window_step_s4_has_all_to_all(topo, no_cache):
