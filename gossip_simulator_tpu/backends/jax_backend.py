@@ -400,8 +400,8 @@ class JaxStepper(Stepper):
         tree = {k: np.array(v) for k, v in self.state._asdict().items()}
         if "pending" in tree:
             # The ring engine's flat delay ring is saved in its logical
-            # (d, n) shape, the sharded backend's, so snapshots move
-            # between the two (epidemic.ring_row).
+            # (d, n) shape, as the sharded backend saves its shards' rings,
+            # so snapshots move between the two (epidemic.ring_row).
             tree["pending"] = tree["pending"].reshape(-1, self.cfg.n)
         if "mail_ids" in tree:
             # Record the mail-ring geometry so a future build whose AUTO

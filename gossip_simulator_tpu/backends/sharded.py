@@ -52,7 +52,9 @@ class ShardedStepper(Stepper):
     def __init__(self, cfg, n_devices: int | None = None):
         super().__init__(cfg)
         self.mesh = node_mesh(n_devices)
-        shard_size(cfg.n, self.mesh)  # validate divisibility early
+        # Validate divisibility and the per-shard ring's int32 bound early.
+        cfg.check_ring_rows(shard_size(cfg.n, self.mesh),
+                            shards=int(self.mesh.shape[AXIS]))
 
     def init(self) -> None:
         cfg = self.cfg
@@ -410,11 +412,15 @@ class ShardedStepper(Stepper):
         """Host-gathered snapshot (np.asarray collects all shards).  The
         event mail ring is S per-shard rings concatenated, so mail_geom
         records the PER-SHARD slot geometry plus the shard count -- a
-        snapshot restores onto the same device count only (the ring engine's
-        state is layout-independent and restores onto any mesh)."""
+        snapshot restores onto the same device count only.  The ring
+        engine's per-shard flat delay rings are saved as the logical (d, n)
+        ring, the single-device backend's form, so its state restores onto
+        any mesh and onto `-backend jax`."""
         if self.state is None:
             return None
         tree = {k: _host_gather(v) for k, v in self.state._asdict().items()}
+        if "pending" in tree:
+            tree["pending"] = self._ring_logical(tree["pending"])
         if "mail_ids" in tree:
             cfg = self.cfg
             if cfg.model == "pushsum":
@@ -458,6 +464,7 @@ class ShardedStepper(Stepper):
             cls, specs = EventState, event_sharded.event_state_specs(cfg)
         else:
             cls, specs = SimState, sharded_step.sim_state_specs(cfg)
+            tree["pending"] = self._ring_per_shard(tree["pending"])
         # jnp.array (device COPY) before placement: on the CPU platform
         # device_put of a host array can be zero-copy, and the restored
         # leaves feed straight into DONATING jitted fns -- XLA then reuses
@@ -471,3 +478,19 @@ class ShardedStepper(Stepper):
             for k, v in tree.items()})
         self._overlay_done = True
         self._seeded = True  # snapshots are taken mid-phase-2
+
+    def _ring_logical(self, flat: np.ndarray) -> np.ndarray:
+        """The shards' flat rings side by side, (S, d, n_local), as the
+        logical (d, n) ring: slot t of node s * n_local + r."""
+        s = int(self.mesh.shape[AXIS])
+        n_local = shard_size(self.cfg.n, self.mesh)
+        return (flat.reshape(s, -1, n_local).transpose(1, 0, 2)
+                .reshape(-1, self.cfg.n))
+
+    def _ring_per_shard(self, ring: np.ndarray) -> np.ndarray:
+        """Inverse of _ring_logical: (d, n) to each shard's flat ring."""
+        s = int(self.mesh.shape[AXIS])
+        n_local = shard_size(self.cfg.n, self.mesh)
+        d = ring.shape[0]
+        return np.ascontiguousarray(
+            ring.reshape(d, s, n_local).transpose(1, 0, 2)).reshape(-1)

@@ -1242,18 +1242,32 @@ class Config:
                     "-resume requires backend=jax or sharded")
         if self.fanout >= self.n:
             raise ValueError(f"fanout ({self.fanout}) must be < n ({self.n})")
-        if self.backend == "jax" and self.engine_resolved == "ring":
-            # The single-device ring is one flat int32[d * n] array whose
-            # int32 scatter index uses d * n as its drop sentinel
-            # (epidemic.deposit_local); d is epidemic.ring_depth.
-            d = self.delayhigh if self.effective_time_mode == "ticks" else 2
-            if d * self.n >= 2**31 - 1:
-                raise ValueError(
-                    f"the ring engine's delay ring ({d} slots from "
-                    f"-delayhigh, times -n {self.n}) holds {d * self.n} "
-                    f"cells, over the int32 index bound {2**31 - 1}; lower "
-                    "-delayhigh or -n, or run -backend sharded")
+        if self.backend == "jax":
+            self.check_ring_rows(self.n)
         return self
+
+    def check_ring_rows(self, rows: int, shards: int = 1) -> None:
+        """Refuse a ring-engine run whose delay ring cannot be indexed in
+        int32.  The ring is one flat int32[d * rows] array per device
+        (`rows` = n on one device, n / shards per shard) whose scatter
+        index uses d * rows as its drop sentinel (epidemic.deposit_local);
+        d is epidemic.ring_depth.  The sharded wire word, rows * d + slot
+        (exchange.pack_dst_slot), has the same bound.  validate() checks
+        `-backend jax`; the sharded backend checks its shard once the mesh
+        is known (ShardedStepper)."""
+        if self.engine_resolved != "ring":
+            return
+        d = self.delayhigh if self.effective_time_mode == "ticks" else 2
+        if d * rows < 2**31 - 1:
+            return
+        where = (f"-n {self.n}" if shards == 1 else
+                 f"-n {self.n} over {shards} shards, {rows} per shard")
+        raise ValueError(
+            f"the ring engine's delay ring ({d} slots from -delayhigh, "
+            f"times {where}) holds {d * rows} cells per device, over the "
+            f"int32 index bound {2**31 - 1}; lower -delayhigh or -n"
+            + (", or run -backend sharded" if shards == 1 else
+               ", or run on more devices"))
 
     def replace(self, **kw) -> "Config":
         return dataclasses.replace(self, **kw).validate()

@@ -13,8 +13,8 @@ draws ONE shared delay uniform in [delaylow, delayhigh) ticks -- exactly the
 reference's RandomNetworkDelay applied once per broadcast goroutine
 (simulator.go:141-142) -- and each link send has an independent drop draw
 (simulator.go:144).  Messages sit in a ``pending`` ring buffer of d slots of
-n arrival *counts* (flat ``[d * n]`` single-device, ``[d, n_local]`` per
-shard: ring_row) so duplicate deliveries are counted like the reference's
+n arrival *counts* (one flat ``[d * n]`` array, per shard on the sharded
+backend: ring_row) so duplicate deliveries are counted like the reference's
 TotalMessage (simulator.go:111) while infection stays an idempotent OR.
 
 "rounds" mode is the classic synchronous-epidemic accounting: every hop takes
@@ -54,8 +54,10 @@ SEED_TICK = 0x7FFFFFFF  # reserved "tick" for the one-off seed draws (fold_in ne
 # node-local tick (tick_core, counters), EMIT sender compaction, drop and
 # delay draws and the edge list, DELIVER the scatter-add into the delay
 # ring with the compaction chunk loop around it, POLL the per-window loop
-# predicate and telemetry row.
+# predicate and telemetry row.  The sharded engine adds ROUTE, the
+# all_to_all exchange with its pack and unpack (parallel/sharded_step.py).
 COMBINE, EMIT, DELIVER, POLL = "combine", "emit", "deliver", "poll"
+ROUTE = "route"
 
 
 def ring_depth(cfg: Config) -> int:
@@ -105,11 +107,10 @@ def pack_rumor_bits(bits: jnp.ndarray, w: int) -> jnp.ndarray:
 def init_state(cfg: Config, friends: jnp.ndarray, friend_cnt: jnp.ndarray,
                n_local: int | None = None, n_shards: int = 1) -> SimState:
     """SimState for the whole node axis, or one shard's slice of it when
-    the sharded backend passes `n_local`.  The single-device delay ring is
-    flat and the per-shard one 2-D (ring_row)."""
+    the sharded backend passes `n_local`.  The delay ring is flat, the
+    shard's own when sharded (ring_row)."""
     n = n_local if n_local is not None else cfg.n
     d = ring_depth(cfg)
-    ring = (d * n,) if n_local is None else (d, n)
     d_rb = d if cfg.protocol == "sir" else 1
     z = lambda: jnp.zeros((), I32)
     pending_rumors, rumor_words, rumor_recv, rumor_done = init_rumor_leaves(
@@ -120,7 +121,7 @@ def init_state(cfg: Config, friends: jnp.ndarray, friend_cnt: jnp.ndarray,
         removed=jnp.zeros((n,), bool),
         friends=friends,
         friend_cnt=friend_cnt,
-        pending=jnp.zeros(ring, I32),
+        pending=jnp.zeros((d * n,), I32),
         rebroadcast=jnp.zeros((d_rb, n), bool),
         tick=z(), total_message=msg64_zero(), total_received=z(),
         total_crashed=z(),
@@ -149,20 +150,17 @@ def row_slot(cfg: Config, delay_key, tick, rows):
 
 
 def ring_row(pending, slot, n: int):
-    """Ring slot `slot`'s n arrival counts.  The single-device ring is flat,
-    int32[d * n] with slot s at [s * n, (s + 1) * n) (deposit_local says
-    why); the sharded engine's per-shard ring is int32[d, n_local]."""
-    if pending.ndim == 1:
-        return jax.lax.dynamic_slice(pending, (slot * n,), (n,))
-    return pending[slot]
+    """Ring slot `slot`'s n arrival counts.  The ring is flat, int32[d * n]
+    with slot s at [s * n, (s + 1) * n) (deposit_local says why); on the
+    sharded backend each shard holds its own such ring over its n_local
+    rows."""
+    return jax.lax.dynamic_slice(pending, (slot * n,), (n,))
 
 
 def ring_clear(pending, slot, n: int):
-    """`pending` with slot `slot` zeroed (either form, see ring_row)."""
-    if pending.ndim == 1:
-        return jax.lax.dynamic_update_slice(
-            pending, jnp.zeros((n,), pending.dtype), (slot * n,))
-    return pending.at[slot].set(0)
+    """`pending` with slot `slot` zeroed (see ring_row)."""
+    return jax.lax.dynamic_update_slice(
+        pending, jnp.zeros((n,), pending.dtype), (slot * n,))
 
 
 def tick_keys(base_key: jax.Array, tick, shard: jax.Array | int | None = None):
@@ -413,37 +411,32 @@ def deposit_compact(cfg: Config, pending, friends, friend_cnt,
     return pending, 0
 
 
-def deposit_local(pending, dst_local, slots, valid, kernel="xla", n=None):
-    """Scatter arrivals into the pending ring (idempotent counting add;
-    duplicates accumulate like the reference's per-message channel sends).
-    A flat ring (ring_row) needs its row length `n`.
+def deposit_local(pending, dst_local, slots, valid, *, n: int,
+                  kernel="xla"):
+    """Scatter arrivals into the flat pending ring of `n` rows per slot
+    (ring_row; idempotent counting add: duplicates accumulate like the
+    reference's per-message channel sends).
 
-    NOTE: keep the single-device ring flat.  The TPU compiler lowers a
-    scatter-add only onto a linear buffer, so on a 2-D [d, n] ring it
-    copied the whole ring out to a flat buffer and back around every call
-    -- once per compaction chunk, most of phase 2's device time.  The flat
-    scatter runs in place.  An invalid lane indexes d * n, outside the ring
-    for every slot, so mode="drop" discards it explicitly; the 2-D form's
-    sentinel dst = n would flatten to node 0 of the NEXT slot.
+    NOTE: keep the ring flat, on one device and per shard.  The TPU
+    compiler lowers a scatter-add only onto a linear buffer, so on a 2-D
+    [d, n] ring it copied the whole ring out to a flat buffer and back
+    around every call -- once per compaction chunk, most of phase 2's
+    device time.  The flat scatter runs in place.  An invalid lane indexes
+    d * n, outside the ring for every slot, so mode="drop" discards it
+    explicitly (a sentinel dst = n would land on node 0 of the NEXT slot).
     chip_smoke.py's pinned totals check that the drop applies on the chip.
     kernel="pallas" routes to the fused serial add
     (ops/pallas_deliver.fused_deposit_add) whose in-range check replaces
     the scatter's OOB-drop explicitly -- integer adds commute, so it is
     bit-identical."""
-    flat = pending.ndim == 1
-    if not flat:
-        n = pending.shape[1]
     if kernel == "pallas":
         from gossip_simulator_tpu.ops import pallas_deliver
         dst = jnp.where(valid, dst_local, n)
         out = pallas_deliver.fused_deposit_add(pending.reshape(-1, n),
                                                slots, dst)
         return out.reshape(pending.shape)
-    if flat:
-        idx = jnp.where(valid, slots * n + dst_local, pending.shape[0])
-        return pending.at[idx].add(1, mode="drop")
-    dst = jnp.where(valid, dst_local, n)  # out of bounds -> mode="drop"
-    return pending.at[slots, dst].add(1, mode="drop")
+    idx = jnp.where(valid, slots * n + dst_local, pending.shape[0])
+    return pending.at[idx].add(1, mode="drop")
 
 
 def deposit_rumors(pending_rumors, dst_local, slots, valid, newbits,

@@ -61,7 +61,7 @@ class SimState(NamedTuple):
     removed: jnp.ndarray  # bool[n]    SIR only; removed => stops forwarding
     friends: jnp.ndarray  # int32[n, k]  -1-padded adjacency (simulator.go:45)
     friend_cnt: jnp.ndarray  # int32[n]
-    pending: jnp.ndarray  # int32[d * n] (per shard [d, n]) arrival-count ring
+    pending: jnp.ndarray  # int32[d * n] arrival-count ring (per shard: ring_row)
     rebroadcast: jnp.ndarray  # bool[d, n]  SIR re-broadcast schedule
     tick: jnp.ndarray  # int32[]
     total_message: jnp.ndarray  # uint32[2] hi/lo 64-bit pair (simulator.go:31)

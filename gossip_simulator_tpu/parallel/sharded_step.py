@@ -9,9 +9,18 @@ reference's shared `GlobalView` + atomics (simulator.go:24-31).
 Layout (S shards, n = S * n_local):
     received/crashed/removed/friend_cnt: [n]      -> P("nodes")
     friends:                             [n, k]   -> P("nodes", None)
-    pending/rebroadcast:                 [d, n]   -> P(None, "nodes")
+    pending:                  [S * d * n_local]   -> P("nodes")
+    rebroadcast:                         [d, n]   -> P(None, "nodes")
     tick / totals:                       scalars  -> replicated
-Global node id of local row r on shard s: s * n_local + r.
+Global node id of local row r on shard s: s * n_local + r.  Each shard's
+slice of `pending` is its own flat delay ring (epidemic.ring_row: slot t
+at [t * n_local, (t + 1) * n_local)), so the global array is the shards'
+rings side by side, not a [d, n] ring; snapshots convert
+(ShardedStepper.state_pytree).
+
+Named scopes (metadata only, as in the single-device engine): COMBINE,
+EMIT, DELIVER and POLL as epidemic.py has them, and ROUTE around each
+all_to_all exchange with its pack and unpack.
 """
 
 from __future__ import annotations
@@ -42,7 +51,7 @@ def sim_state_specs(cfg: Config) -> SimState:
     return SimState(
         received=P(AXIS), crashed=P(AXIS), removed=P(AXIS),
         friends=P(AXIS, None), friend_cnt=P(AXIS),
-        pending=P(None, AXIS), rebroadcast=P(None, AXIS),
+        pending=P(AXIS), rebroadcast=P(None, AXIS),
         tick=P(), total_message=P(), total_received=P(), total_crashed=P(),
         exchange_overflow=P(),
         down_since=P(AXIS) if cfg.faults_enabled else P(),
@@ -92,16 +101,21 @@ def _deposit_routed(cfg: Config, n_local: int, n_shards: int, pending,
     panels' exch_counts leaf) accumulates the route's traffic and a 3rd
     value returns the updated leaf."""
     d = epidemic.ring_depth(cfg)
-    dest_shard = jnp.where(valid, dst_global // n_local, n_shards)
-    dst_local = jnp.where(valid, dst_global % n_local, 0)
-    packed = jnp.where(valid, exchange.pack_dst_slot(dst_local, slots, d), -1)
-    out = exchange.route_one(packed, dest_shard, valid, n_shards, cap,
-                             traffic=exch)
-    (recv, overflow), exch = out[:2], out[2] if exch is not None else None
-    rvalid = recv >= 0
-    rdst, rslot = exchange.unpack_dst_slot(jnp.maximum(recv, 0), d)
-    pending = epidemic.deposit_local(pending, rdst, rslot, rvalid,
-                                     kernel=cfg.deliver_kernel_resolved)
+    with jax.named_scope(epidemic.ROUTE):
+        dest_shard = jnp.where(valid, dst_global // n_local, n_shards)
+        dst_local = jnp.where(valid, dst_global % n_local, 0)
+        packed = jnp.where(valid,
+                           exchange.pack_dst_slot(dst_local, slots, d), -1)
+        out = exchange.route_one(packed, dest_shard, valid, n_shards, cap,
+                                 traffic=exch)
+        (recv, overflow), exch = out[:2], (out[2] if exch is not None
+                                           else None)
+        rvalid = recv >= 0
+        rdst, rslot = exchange.unpack_dst_slot(jnp.maximum(recv, 0), d)
+    with jax.named_scope(epidemic.DELIVER):
+        pending = epidemic.deposit_local(pending, rdst, rslot, rvalid,
+                                         n=n_local,
+                                         kernel=cfg.deliver_kernel_resolved)
     if exch is None:
         return pending, overflow
     return pending, overflow, exch
@@ -119,26 +133,30 @@ def _route_stage_si(cfg: Config, n_local: int, n_shards: int, dst_global,
     and deposits replay in the serial FIFO order.  Returns
     (stage_new, overflow, pstage_threaded[, exch])."""
     d = epidemic.ring_depth(cfg)
-    dest_shard = jnp.where(valid, dst_global // n_local, n_shards)
-    dst_local = jnp.where(valid, dst_global % n_local, 0)
-    packed = jnp.where(valid, exchange.pack_dst_slot(dst_local, slots, d), -1)
-    out = exchange.route_multi_pipelined(
-        (packed,), dest_shard, valid, n_shards, cap, pstage, traffic=exch)
-    ((recv,), overflow, pstage), exch = out[:3], (out[3]
-                                                  if exch is not None
-                                                  else None)
-    rvalid = recv >= 0
-    rdst, rslot = exchange.unpack_dst_slot(jnp.maximum(recv, 0), d)
+    with jax.named_scope(epidemic.ROUTE):
+        dest_shard = jnp.where(valid, dst_global // n_local, n_shards)
+        dst_local = jnp.where(valid, dst_global % n_local, 0)
+        packed = jnp.where(valid,
+                           exchange.pack_dst_slot(dst_local, slots, d), -1)
+        out = exchange.route_multi_pipelined(
+            (packed,), dest_shard, valid, n_shards, cap, pstage,
+            traffic=exch)
+        ((recv,), overflow, pstage), exch = out[:3], (
+            out[3] if exch is not None else None)
+        rvalid = recv >= 0
+        rdst, rslot = exchange.unpack_dst_slot(jnp.maximum(recv, 0), d)
     if exch is None:
         return (rdst, rslot, rvalid), overflow, pstage
     return (rdst, rslot, rvalid), overflow, pstage, exch
 
 
-def _flush_deposit(cfg: Config, pending, stage):
+def _flush_deposit(cfg: Config, n_local: int, pending, stage):
     """Apply a staged deposit (the deferred half of _route_stage_si)."""
     rdst, rslot, rvalid = stage
-    return epidemic.deposit_local(pending, rdst, rslot, rvalid,
-                                  kernel=cfg.deliver_kernel_resolved)
+    with jax.named_scope(epidemic.DELIVER):
+        return epidemic.deposit_local(pending, rdst, rslot, rvalid,
+                                      n=n_local,
+                                      kernel=cfg.deliver_kernel_resolved)
 
 
 def _empty_deposit_stage(n_lanes: int):
@@ -166,25 +184,34 @@ def make_sharded_tick(cfg: Config, mesh):
     def tick_shard(st: SimState, base_key: jax.Array) -> SimState:
         shard = jax.lax.axis_index(AXIS)
         gid0 = shard * n_local
-        # Scenario faults draw on (tick, GLOBAL-id) keys -- shard-count
-        # invariant, unlike the shard-folded step keys below -- so a
-        # scenario trajectory's crash/recovery schedule is identical on
-        # any mesh (and across a reshard-resume).
-        st, dsc, dsr = epidemic.apply_fault_window(
-            cfg, st, gid0 + jnp.arange(n_local, dtype=I32), base_key)
-        keys = epidemic.tick_keys(base_key, st.tick, shard)
-        stp, senders, dslot, (dm, dr, dc) = epidemic.tick_core(cfg, st, keys)
+        with jax.named_scope(epidemic.COMBINE):
+            # Scenario faults draw on (tick, GLOBAL-id) keys -- shard-count
+            # invariant, unlike the shard-folded step keys below -- so a
+            # scenario trajectory's crash/recovery schedule is identical on
+            # any mesh (and across a reshard-resume).
+            st, dsc, dsr = epidemic.apply_fault_window(
+                cfg, st, gid0 + jnp.arange(n_local, dtype=I32), base_key)
+            keys = epidemic.tick_keys(base_key, st.tick, shard)
+            stp, senders, dslot, (dm, dr, dc) = epidemic.tick_core(
+                cfg, st, keys)
         width = stp.friends.shape[1]
         zblk = jnp.zeros((), I32)
         ovf0 = exchange.ovf_join(jnp.zeros((), I32),
                                  st.exch_counts if spatial else None)
+
+        def gather(remaining, ccap, **kw):
+            with jax.named_scope(epidemic.EMIT):
+                return epidemic.compact_gather(
+                    cfg, stp.friends, stp.friend_cnt, dslot, keys["delay"],
+                    keys["drop"], st.tick, remaining, ccap, **kw)
+
         if cfg.compact_resolved:
+            # The chunk loop is DELIVER, with EMIT (the gathers) and ROUTE
+            # (the exchanges) inside it, as in epidemic.make_tick_fn.
             # Compacted wave: only sender rows reach the RNG/sort/all_to_all.
             # Chunk count is agreed across shards (pmax) so every shard
             # executes the same number of collectives.
             ccap = epidemic.compact_chunk_cap(cfg, n_local)
-            count = jax.lax.pmax(senders.sum(dtype=I32), AXIS)
-            chunks = (count + ccap - 1) // ccap
             # Per-chunk route cap: destination-uniform graphs size the
             # wire from the per-pair high-water mark (round 6 --
             # exchange.chernoff_cap, same gate as the event engine's
@@ -197,6 +224,9 @@ def make_sharded_tick(cfg: Config, mesh):
             else:
                 rcap = min(exchange.epidemic_cap(n_local, width, s),
                            ccap * width)
+            with jax.named_scope(epidemic.DELIVER):
+                count = jax.lax.pmax(senders.sum(dtype=I32), AXIS)
+                chunks = (count + ccap - 1) // ccap
 
             if pipe:
                 # Pipelined chunk loop (-exchange-pipeline double): chunk
@@ -206,35 +236,31 @@ def make_sharded_tick(cfg: Config, mesh):
                 def body_pipe(_, carry):
                     pending, remaining, ovf, blk, pend = carry
                     oacc, exch = exchange.ovf_split(ovf)
-                    (dstg, slots, valid, remaining,
-                     b2) = epidemic.compact_gather(
-                        cfg, stp.friends, stp.friend_cnt, dslot,
-                        keys["delay"], keys["drop"], st.tick, remaining,
-                        ccap, **(dict(gid0=gid0) if track_part else {}))
+                    (dstg, slots, valid, remaining, b2) = gather(
+                        remaining, ccap,
+                        **(dict(gid0=gid0) if track_part else {}))
                     out = _route_stage_si(
                         cfg, n_local, s, dstg, slots, valid, rcap, pend,
                         exch=exch)
                     (nstage, o, pthr), exch = out[:3], (
                         out[3] if exch is not None else None)
-                    pending = _flush_deposit(cfg, pending, pthr)
+                    pending = _flush_deposit(cfg, n_local, pending, pthr)
                     return (pending, remaining,
                             exchange.ovf_join(oacc + o, exch),
                             blk + (b2 if track_part else 0), nstage)
 
-                pending, _, ovf, blk, pend = jax.lax.fori_loop(
-                    0, chunks, body_pipe,
-                    (stp.pending, senders, ovf0, zblk,
-                     _empty_deposit_stage(s * rcap)))
-                pending = _flush_deposit(cfg, pending, pend)
+                with jax.named_scope(epidemic.DELIVER):
+                    pending, _, ovf, blk, pend = jax.lax.fori_loop(
+                        0, chunks, body_pipe,
+                        (stp.pending, senders, ovf0, zblk,
+                         _empty_deposit_stage(s * rcap)))
+                pending = _flush_deposit(cfg, n_local, pending, pend)
             elif track_part:
                 def body_p(_, carry):
                     pending, remaining, ovf, blk = carry
                     oacc, exch = exchange.ovf_split(ovf)
-                    (dstg, slots, valid, remaining,
-                     b2) = epidemic.compact_gather(
-                        cfg, stp.friends, stp.friend_cnt, dslot,
-                        keys["delay"], keys["drop"], st.tick, remaining,
-                        ccap, gid0=gid0)
+                    (dstg, slots, valid, remaining, b2) = gather(
+                        remaining, ccap, gid0=gid0)
                     out = _deposit_routed(cfg, n_local, s, pending,
                                           dstg, slots, valid, rcap,
                                           exch=exch)
@@ -243,18 +269,16 @@ def make_sharded_tick(cfg: Config, mesh):
                     return (pending, remaining,
                             exchange.ovf_join(oacc + o, exch), blk + b2)
 
-                pending, _, ovf, blk = jax.lax.fori_loop(
-                    0, chunks, body_p,
-                    (stp.pending, senders, ovf0, zblk))
+                with jax.named_scope(epidemic.DELIVER):
+                    pending, _, ovf, blk = jax.lax.fori_loop(
+                        0, chunks, body_p,
+                        (stp.pending, senders, ovf0, zblk))
             else:
                 def body(_, carry):
                     pending, remaining, ovf = carry
                     oacc, exch = exchange.ovf_split(ovf)
-                    (dstg, slots, valid, remaining,
-                     _b) = epidemic.compact_gather(
-                        cfg, stp.friends, stp.friend_cnt, dslot,
-                        keys["delay"], keys["drop"], st.tick, remaining,
-                        ccap)
+                    (dstg, slots, valid, remaining, _b) = gather(
+                        remaining, ccap)
                     out = _deposit_routed(cfg, n_local, s, pending,
                                           dstg, slots, valid, rcap,
                                           exch=exch)
@@ -263,14 +287,16 @@ def make_sharded_tick(cfg: Config, mesh):
                     return (pending, remaining,
                             exchange.ovf_join(oacc + o, exch))
 
-                pending, _, ovf = jax.lax.fori_loop(
-                    0, chunks, body,
-                    (stp.pending, senders, ovf0))
+                with jax.named_scope(epidemic.DELIVER):
+                    pending, _, ovf = jax.lax.fori_loop(
+                        0, chunks, body,
+                        (stp.pending, senders, ovf0))
                 blk = zblk
         else:
-            dst, slots, valid, blk = epidemic.edges_from_senders(
-                cfg, stp.friends, stp.friend_cnt, senders, dslot,
-                keys["drop"], tick=st.tick, gid0=gid0)
+            with jax.named_scope(epidemic.EMIT):
+                dst, slots, valid, blk = epidemic.edges_from_senders(
+                    cfg, stp.friends, stp.friend_cnt, senders, dslot,
+                    keys["drop"], tick=st.tick, gid0=gid0)
             exch = st.exch_counts if spatial else None
             out = _deposit_routed(
                 cfg, n_local, s, stp.pending, dst, slots, valid,
@@ -278,29 +304,32 @@ def make_sharded_tick(cfg: Config, mesh):
             (pending, ovf), exch = out[:2], (
                 out[2] if exch is not None else None)
             ovf = exchange.ovf_join(ovf, exch)
-        # Traffic rows are per-shard gauges: split them off BEFORE the psum.
-        ovf, exch = exchange.ovf_split(ovf)
-        dm, dr, dc, ovf = jax.lax.psum((dm, dr, dc, ovf), AXIS)
-        # NOTE: no lax.cond empty-slot skip here -- see the NOTE in
-        # epidemic.make_tick_fn (cond + dynamic fori).
-        # The psum'd per-tick delta stays int32 (bounded by the delay-ring
-        # capacity); the carry into the 64-bit pair is replicated per shard.
-        stp = stp._replace(
-            pending=pending,
-            total_message=msg64_add(stp.total_message, dm),
-            total_received=stp.total_received + dr,
-            total_crashed=stp.total_crashed + dc,
-            exchange_overflow=stp.exchange_overflow + ovf)
-        if exch is not None:
-            stp = stp._replace(exch_counts=exch)
-        if cfg.scenario_resolved.active:
-            dsc, dsr, blk = jax.lax.psum(
-                (jnp.asarray(dsc, I32), jnp.asarray(dsr, I32),
-                 jnp.asarray(blk, I32)), AXIS)
+        with jax.named_scope(epidemic.COMBINE):
+            # Traffic rows are per-shard gauges: split them off BEFORE the
+            # psum.
+            ovf, exch = exchange.ovf_split(ovf)
+            dm, dr, dc, ovf = jax.lax.psum((dm, dr, dc, ovf), AXIS)
+            # NOTE: no lax.cond empty-slot skip here -- see the NOTE in
+            # epidemic.make_tick_fn (cond + dynamic fori).
+            # The psum'd per-tick delta stays int32 (bounded by the
+            # delay-ring capacity); the carry into the 64-bit pair is
+            # replicated per shard.
             stp = stp._replace(
-                scen_crashed=stp.scen_crashed + dsc,
-                scen_recovered=stp.scen_recovered + dsr,
-                part_dropped=stp.part_dropped + blk)
+                pending=pending,
+                total_message=msg64_add(stp.total_message, dm),
+                total_received=stp.total_received + dr,
+                total_crashed=stp.total_crashed + dc,
+                exchange_overflow=stp.exchange_overflow + ovf)
+            if exch is not None:
+                stp = stp._replace(exch_counts=exch)
+            if cfg.scenario_resolved.active:
+                dsc, dsr, blk = jax.lax.psum(
+                    (jnp.asarray(dsc, I32), jnp.asarray(dsr, I32),
+                     jnp.asarray(blk, I32)), AXIS)
+                stp = stp._replace(
+                    scen_crashed=stp.scen_crashed + dsc,
+                    scen_recovered=stp.scen_recovered + dsr,
+                    part_dropped=stp.part_dropped + blk)
         return stp
 
     return tick_shard
@@ -466,7 +495,7 @@ def make_sharded_heal(cfg: Config, mesh):
         # shard-local, so they skip the route.
         pdst = jnp.broadcast_to(rows[:, None], (n_local, k)).reshape(-1)
         pending = epidemic.deposit_local(pending, pdst, slots,
-                                         pull.reshape(-1),
+                                         pull.reshape(-1), n=n_local,
                                          kernel=cfg.deliver_kernel_resolved)
         rep, blk, ovf = jax.lax.psum(
             (rep, jnp.asarray(blk, I32), ovf), AXIS)
@@ -507,17 +536,20 @@ def make_sharded_seed(cfg: Config, mesh):
                                total_received=total_received)
         dslot = epidemic.row_slot(cfg, kd, st.tick,
                                   jnp.arange(n_local, dtype=I32))
-        dst, slots, valid, blk = epidemic.edges_from_senders(
-            cfg, st.friends, st.friend_cnt, is_sender, dslot, kp,
-            tick=st.tick, gid0=shard * n_local)
+        # Only the sender's row emits: gather it (row-keyed draws, so the
+        # same edges as a dense pass over every row) and route its k
+        # lanes.  A dense [n_local, k] wave did not fit a chip's HBM at
+        # 25M rows per shard.
+        k = st.friends.shape[1]
+        dst, slots, valid, _, blk = epidemic.compact_gather(
+            cfg, st.friends, st.friend_cnt, dslot, kd, kp, st.tick,
+            is_sender, 1, gid0=shard * n_local)
         if cfg.scenario_resolved.has_partitions:
             st = st._replace(part_dropped=st.part_dropped
                              + jax.lax.psum(blk, AXIS))
         exch = st.exch_counts if spatial else None
-        out = _deposit_routed(
-            cfg, n_local, s, st.pending, dst, slots, valid,
-            exchange.epidemic_cap(n_local, st.friends.shape[1], s),
-            exch=exch)
+        out = _deposit_routed(cfg, n_local, s, st.pending, dst, slots, valid,
+                              k, exch=exch)
         (pending, ovf), exch = out[:2], (
             out[2] if exch is not None else None)
         rb = st.rebroadcast
@@ -664,17 +696,18 @@ def make_run_to_coverage_fn(cfg: Config, mesh, telemetry: bool = False):
                        and not cfg.overlay_heal_resolved)
 
     def cond_live(s, target_count, until):
-        live = ((s.total_received < target_count)
-                & (s.tick < max_steps) & (s.tick < until))
-        if check_in_flight:
-            # psum of each shard's ring-occupied indicator
-            # (replicated, so every shard agrees): exit at wave
-            # death instead of spinning to the bounded-call budget
-            # -- same term the sharded event engine's cond has
-            # (event_sharded.make_run_to_coverage_fn).
-            live = live & (jax.lax.psum(state_mod.in_flight(s),
-                                        AXIS) > 0)
-        return live
+        with jax.named_scope(epidemic.POLL):
+            live = ((s.total_received < target_count)
+                    & (s.tick < max_steps) & (s.tick < until))
+            if check_in_flight:
+                # psum of each shard's ring-occupied indicator
+                # (replicated, so every shard agrees): exit at wave
+                # death instead of spinning to the bounded-call budget
+                # -- same term the sharded event engine's cond has
+                # (event_sharded.make_run_to_coverage_fn).
+                live = live & (jax.lax.psum(state_mod.in_flight(s),
+                                            AXIS) > 0)
+            return live
 
     def advance(s, base_key):
         s = jax.lax.fori_loop(0, window, lambda _, x: step(x, base_key), s)
@@ -700,15 +733,16 @@ def make_run_to_coverage_fn(cfg: Config, mesh, telemetry: bool = False):
                 def body(carry):
                     s, h = carry
                     s = advance(s, base_key)
-                    row = telem.gossip_probe(
-                        s, sir, psum=lambda x: jax.lax.psum(x, AXIS),
-                        pmax=lambda x: jax.lax.pmax(x, AXIS),
-                        inflight_hwm=ihwm)
-                    return s, telem.record_window(
-                        h, row, st=s, spec=spatial,
-                        shard_index=jax.lax.axis_index(AXIS),
-                        gather=lambda x: jax.lax.all_gather(x, AXIS),
-                        psum=lambda x: jax.lax.psum(x, AXIS))
+                    with jax.named_scope(epidemic.POLL):
+                        row = telem.gossip_probe(
+                            s, sir, psum=lambda x: jax.lax.psum(x, AXIS),
+                            pmax=lambda x: jax.lax.pmax(x, AXIS),
+                            inflight_hwm=ihwm)
+                        return s, telem.record_window(
+                            h, row, st=s, spec=spatial,
+                            shard_index=jax.lax.axis_index(AXIS),
+                            gather=lambda x: jax.lax.all_gather(x, AXIS),
+                            psum=lambda x: jax.lax.psum(x, AXIS))
 
                 return jax.lax.while_loop(cond, body, (st, hist))
 

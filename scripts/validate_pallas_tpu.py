@@ -238,14 +238,14 @@ def run_deliver_checks() -> dict:
 
     B, k, Wr = 4, 5, 3
     md = n * k
-    pending = jnp.asarray(rng.integers(0, 3, (B, n)), I32)
+    pending = jnp.asarray(rng.integers(0, 3, B * n), I32)
     slots = jnp.asarray(rng.integers(0, B, md), I32)
     dvalid = jnp.asarray(rng.random(md) < 0.7)
     ddst = jnp.asarray(rng.integers(0, n, md), I32)
     add("deposit_local",
-        eq((epidemic.deposit_local(pending, ddst, slots, dvalid,
+        eq((epidemic.deposit_local(pending, ddst, slots, dvalid, n=n,
                                    kernel="pallas"),
-            epidemic.deposit_local(pending, ddst, slots, dvalid,
+            epidemic.deposit_local(pending, ddst, slots, dvalid, n=n,
                                    kernel="xla"))))
     pr = jnp.asarray(rng.integers(0, 3, (B, n, Wr)), I32)
     newbits = jnp.asarray(rng.random((n, Wr)) < 0.5)
@@ -350,10 +350,10 @@ def run_megakernel_checks() -> dict:
     p0 = jnp.asarray(rng.integers(0, 3, (bs, nn)), I32)
     pr0 = jnp.asarray(rng.integers(0, 3, (bs, nn, rr)), I32)
     fp, fpr = mk.fused_deposit_both(p0, pr0, dst, slots, valid, nb)
-    xp = epidemic.deposit_local(p0, dst, slots, valid)
+    xp = epidemic.deposit_local(p0.reshape(-1), dst, slots, valid, n=nn)
     xpr = epidemic.deposit_rumors(pr0, dst, slots, valid, nb)
     add("deposit_both_parity",
-        bool((fp == xp).all()) and bool((fpr == xpr).all()))
+        bool((fp.reshape(-1) == xp).all()) and bool((fpr == xpr).all()))
 
     return {
         "mode": mode,

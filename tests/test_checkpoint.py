@@ -181,39 +181,49 @@ def test_sharded_ring_resume_reproduces_trajectory(tmp_path):
         assert s2.gossip_window() == want
 
 
-@pytest.mark.parametrize("first", ["jax", "sharded"])
-def test_ring_snapshot_crosses_backends_mid_phase2(tmp_path, first):
-    """The single-device ring engine keeps its delay ring flat but
-    snapshots it as (d, n), the sharded ring's shape.  A mid-phase-2
-    snapshot of either backend restores into the other (S = 1), whose own
+@pytest.mark.parametrize("first,shards", [
+    pytest.param("jax", 1, id="jax"),
+    pytest.param("sharded", 1, id="sharded"),
+    pytest.param("sharded", 4, id="sharded4"),
+])
+def test_ring_snapshot_crosses_backends_mid_phase2(tmp_path, first, shards):
+    """The ring engine keeps its delay ring flat, on one device and per
+    shard, but snapshots it as the logical (d, n) ring.  A mid-phase-2
+    snapshot of either backend restores into the other, whose own
     snapshot restores back, and the run finishes Stats-exact against the
-    uninterrupted one."""
+    uninterrupted one.  At S = 4 the shards' flat rings, side by side in
+    the sharded array, reorder into (d, n) and back."""
     from gossip_simulator_tpu.backends.sharded import ShardedStepper
     from gossip_simulator_tpu.models import epidemic
 
-    base = dict(n=2000, graph="kout", fanout=6, seed=3, crashrate=0.0,
+    n = 2000
+    base = dict(n=n, graph="kout", fanout=6, seed=3, crashrate=0.0,
                 engine="ring", progress=False)
 
     def stepper(backend, **kw):
         cfg = Config(**base, backend=backend, **kw).validate()
         s = (JaxStepper(cfg) if backend == "jax"
-             else ShardedStepper(cfg, n_devices=1))
+             else ShardedStepper(cfg, n_devices=shards))
         s.init()
         return s
 
     resume = dict(resume=True, checkpoint_dir=str(tmp_path))
     other = "sharded" if first == "jax" else "jax"
     s = stepper(first)
-    if first == "jax":
-        assert s.state.pending.shape == (20 * 2000,)
+    d = epidemic.ring_depth(s.cfg)
+    assert s.state.pending.shape == (d * n,)
     s.seed()
     s.gossip_window()
     s.gossip_window()
     mid = s.stats()
     tree, _ = checkpoint.load(
         checkpoint.save(str(tmp_path), 2, s.state_pytree(), mid))
-    assert tree["pending"].shape == (epidemic.ring_depth(s.cfg), 2000)
+    assert tree["pending"].shape == (d, n)
     assert tree["pending"].any()  # messages in flight
+    # Slot t of node g sits in its owner shard's ring at t * n_local + r.
+    flat = np.array(s.state.pending).reshape(shards, d, n // shards)
+    np.testing.assert_array_equal(
+        tree["pending"], np.concatenate(list(flat), axis=1))
     reference = []
     while not reference or reference[-1].coverage < 0.99:
         reference.append(s.gossip_window())

@@ -260,6 +260,30 @@ def test_ring_engine_named_scopes(telemetry):
         assert any(f"/{scope}/" in p for p in paths), scope
 
 
+@pytest.mark.parametrize("telemetry", ["on", "off"])
+def test_sharded_ring_engine_named_scopes(telemetry):
+    """The sharded ring engine's run program at S = 4 carries the four
+    phase-2 scope names and `route` (the all_to_all with its pack and
+    unpack) on its op_name paths; the exchange itself is under route."""
+    import re
+
+    from gossip_simulator_tpu.backends.sharded import ShardedStepper
+
+    cfg = Config(n=4000, backend="sharded", graph="kout", fanout=6, seed=3,
+                 engine="ring", telemetry=telemetry).validate()
+    st = ShardedStepper(cfg, n_devices=4)
+    st.init()
+    args = (st.state, st.key, np.int32(3600), np.int32(64))
+    if st._telem is not None:
+        args += (st._telem.begin_gossip(),)
+    txt = st._run_fn.lower(*args).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', txt)
+    for scope in ("combine", "emit", "route", "deliver", "poll"):
+        assert any(f"/{scope}/" in p for p in paths), scope
+    a2a = re.findall(r'all-to-all\(.*op_name="([^"]*)"', txt)
+    assert a2a and all("/route/" in p for p in a2a), a2a
+
+
 def test_tracer_spans_nest_and_null_path():
     t = trace.Tracer()
     with trace.activated(t):

@@ -289,9 +289,9 @@ def test_deposit_both_parity():
     newbits = jnp.asarray(rng.random((n, W)) < 0.5)
     fp_, fpr = mk.fused_deposit_both(pending, pr, dst, slots, valid,
                                      newbits, interpret=True)
-    xp = epidemic.deposit_local(pending, dst, slots, valid)
+    xp = epidemic.deposit_local(pending.reshape(-1), dst, slots, valid, n=n)
     xpr = epidemic.deposit_rumors(pr, dst, slots, valid, newbits)
-    assert (fp_ == xp).all() and (fpr == xpr).all()
+    assert (fp_.reshape(-1) == xp).all() and (fpr == xpr).all()
 
 
 # --------------------------------------------------------------------------

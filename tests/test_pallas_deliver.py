@@ -196,16 +196,17 @@ def test_ring_append_parity(nrings):
 
 def test_deposit_parity():
     """epidemic.deposit_local / deposit_rumors gates: integer adds commute,
-    so the serial pass is bit-identical to the 2-D OOB-drop scatter."""
+    so the serial pass is bit-identical to the flat OOB-drop scatter."""
     rng = np.random.default_rng(7)
     B, n, k, W = 4, 9, 5, 3
     m = n * k
-    pending = jnp.asarray(rng.integers(0, 3, (B, n)), I32)
+    pending = jnp.asarray(rng.integers(0, 3, B * n), I32)
     slots = jnp.asarray(rng.integers(0, B, m), I32)
     valid = jnp.asarray(rng.random(m) < 0.7)
     dst = jnp.asarray(rng.integers(0, n, m), I32)
-    f = epidemic.deposit_local(pending, dst, slots, valid, kernel="pallas")
-    x = epidemic.deposit_local(pending, dst, slots, valid, kernel="xla")
+    f = epidemic.deposit_local(pending, dst, slots, valid, n=n,
+                               kernel="pallas")
+    x = epidemic.deposit_local(pending, dst, slots, valid, n=n, kernel="xla")
     assert (f == x).all()
     pr = jnp.asarray(rng.integers(0, 3, (B, n, W)), I32)
     newbits = jnp.asarray(rng.random((n, W)) < 0.5)

@@ -15,6 +15,10 @@ step that does not fit HBM, a mesh program without its collective.
   another layout and back.
 * The S=4 sharded window step on a mesh over the described devices, with
   the all-to-all in its compiled text.
+* The sharded ring engine's run program at 1M rows per shard on that
+  mesh: each shard's flat delay ring is written in place, as on one chip.
+* The sharded ring engine's seed program at 25M rows per shard, the
+  north-star cell's, within the chip's HBM.
 
 The topology is described only inside the module fixture below, never
 while a module is imported (one process at a time may load libtpu), and
@@ -210,3 +214,66 @@ def test_sharded_window_step_s4_has_all_to_all(topo, no_cache):
     compiled = event_sharded.make_window_fn(cfg, mesh, WINDOW_MS).lower(
         state, key).compile()
     assert "all-to-all" in compiled.as_text()
+
+
+def test_sharded_ring_run_scatters_into_the_ring_in_place(topo, no_cache):
+    """The sharded ring engine's run program (the north-star cell's path,
+    pipelined exchange) on the described 2x2 mesh at 1M rows per shard.
+    A per-shard 2-D [d, n_local] ring made the compiler copy the ring to a
+    flat buffer and back around the scatter-add in every compaction
+    chunk; each shard's flat ring leaves one ring per loop carry and one
+    ring-sized dynamic-update-slice, the slot clear."""
+    from gossip_simulator_tpu.models import epidemic
+    from gossip_simulator_tpu.parallel import sharded_step
+    from gossip_simulator_tpu.parallel.mesh import AXIS
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    cfg = parse_args(["-n", str(4 * ROWS), "-backend", "sharded",
+                      "-engine", "ring", "-exchange-pipeline", "double",
+                      *SI_FLAGS])
+    ring = epidemic.ring_depth(cfg) * ROWS
+    state = jax.eval_shape(sharded_step.make_sharded_init(cfg, mesh))
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        state, sharded_step.sim_state_specs(cfg))
+    rep = NamedSharding(mesh, PartitionSpec())
+    key = _on(jax.eval_shape(lambda: _rng.base_key(cfg.seed)), rep)
+    count = jax.ShapeDtypeStruct((), I32, sharding=rep)
+    compiled = sharded_step.make_run_to_coverage_fn(cfg, mesh).lower(
+        state, key, count, count).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    ops = _ring_sized(text, ring)
+    loops = [(name, c) for op, name, c in ops if op == "while" and c]
+    assert loops and all(c == 1 for _, c in loops), loops
+    updates = [name for op, name, c in ops
+               if op == "dynamic-update-slice" and c]
+    assert len(updates) == 1, updates
+
+
+def test_sharded_ring_seed_fits_hbm_at_the_north_star_shard(topo, no_cache):
+    """The sharded ring engine's seed program at 25M rows per shard (the
+    north-star cell's 100M over four chips).  The seed emits one row;
+    routing a dense [n_local, k] wave for it asked the compiler for
+    34 GB of temporaries, over twice a chip's HBM."""
+    from gossip_simulator_tpu.parallel import sharded_step
+    from gossip_simulator_tpu.parallel.mesh import AXIS
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    cfg = parse_args(["-n", "100000000", "-backend", "sharded",
+                      "-engine", "ring", *SI_FLAGS])
+    state = jax.eval_shape(sharded_step.make_sharded_init(cfg, mesh))
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        state, sharded_step.sim_state_specs(cfg))
+    key = _on(jax.eval_shape(lambda: _rng.base_key(cfg.seed)),
+              NamedSharding(mesh, PartitionSpec()))
+    mem = sharded_step.make_seed_fn(cfg, mesh).lower(
+        state, key).compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, used
