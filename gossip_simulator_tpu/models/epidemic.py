@@ -42,6 +42,7 @@ from gossip_simulator_tpu.models.state import (SimState, in_flight,
                                                init_exch_counts,
                                                msg64_add, msg64_zero)
 from gossip_simulator_tpu.ops.select import first_true_indices  # noqa: F401  (re-export: compaction callers import it from here)
+from gossip_simulator_tpu.ops.select import chunk_plan, plan_chunk
 from gossip_simulator_tpu.utils import rng as _rng
 
 I32 = jnp.int32
@@ -325,9 +326,9 @@ def edges_from_senders(cfg: Config, friends, friend_cnt, senders, dslot,
 
 def compact_chunk_cap(cfg: Config, n_local: int) -> int:
     """Static sender-compaction chunk size.  In ticks mode the per-tick wave
-    is spread over the delay window; n/128 keeps the per-chunk gather small
-    (first_true_indices touches cap x blk elements) with the chunked loop
-    absorbing peak ticks; rounds mode processes everything at once."""
+    is spread over the delay window; n/128 keeps each chunk's gather and
+    scatter small with the chunked loop absorbing peak ticks; rounds mode
+    processes everything at once."""
     if cfg.compact_chunk > 0:
         return min(n_local, cfg.compact_chunk)
     if cfg.effective_time_mode == "rounds":
@@ -336,18 +337,15 @@ def compact_chunk_cap(cfg: Config, n_local: int) -> int:
 
 
 def compact_gather(cfg: Config, friends, friend_cnt, dslot, delay_key,
-                   drop_key, tick, remaining, cap, gid0=0):
-    """Pull the next <=cap sender rows out of `remaining` and return their
-    edge list (dst, slot, valid) plus the updated remaining mask and the
+                   drop_key, tick, idx, gid0=0):
+    """Edge list (dst, slot, valid) of the sender rows `idx` (one chunk of
+    select.plan_chunk; fill rows, index n, gather as invalid) and the
     scenario-partition block count (Python 0 with no partitions -- see
-    edges_from_senders).  Fill rows (index n) gather as invalid.  Drop
-    masks and delay slots are row-keyed (utils/rng.row_keys), drawn here
-    for just the gathered rows -- bit-identical to the dense path's draws
-    for the same rows (tested)."""
+    edges_from_senders).  Drop masks and delay slots are row-keyed
+    (utils/rng.row_keys), drawn here for just the gathered rows --
+    bit-identical to the dense path's draws for the same rows (tested)."""
     n, k = friends.shape
-    idx = first_true_indices(remaining, cap)
-    hit = jnp.zeros((n,), bool).at[idx].set(True, mode="drop")
-    remaining = remaining & ~hit
+    cap = idx.shape[0]
     sf = friends.at[idx].get(mode="fill", fill_value=-1)
     scnt = friend_cnt.at[idx].get(mode="fill", fill_value=0)
     # Fill rows draw junk (row id n) but their edges are already invalid.
@@ -369,46 +367,40 @@ def compact_gather(cfg: Config, friends, friend_cnt, dslot, delay_key,
         edge = edge & ~blocked
     dst = jnp.where(edge, sf, -1).reshape(-1)
     slots = jnp.broadcast_to(sslot[:, None], (cap, k)).reshape(-1)
-    return dst, slots, edge.reshape(-1), remaining, blocked_n
+    return dst, slots, edge.reshape(-1), blocked_n
 
 
 def deposit_compact(cfg: Config, pending, friends, friend_cnt,
                     senders, dslot, delay_key, drop_key, tick):
     """Compacted equivalent of edges_from_senders + deposit_local: only
     actual sender rows reach the RNG, gather and scatter.  Row-keyed draws
-    keep the trajectory bit-identical to the dense path (tested).  Returns
-    ``(pending, partition_blocked_count)`` -- the count is a Python 0 (and
-    the loop carry is untouched) when no partitions are configured."""
+    keep the trajectory bit-identical to the dense path (tested).  The
+    senders are ranked once (select.chunk_plan); chunk j deposits those of
+    rank [j*cap, (j+1)*cap), and the loop carries the ring, a scalar
+    cursor and the partition count.  Returns ``(pending,
+    partition_blocked_count)`` -- the count is a Python 0 when no
+    partitions are configured."""
     n, k = friends.shape
     cap = compact_chunk_cap(cfg, n)
     dkern = cfg.deliver_kernel_resolved
-    count = senders.sum(dtype=I32)
-    chunks = (count + cap - 1) // cap
-    if cfg.scenario_resolved.has_partitions:
-        def body_p(_, carry):
-            pending, remaining, blk = carry
-            with jax.named_scope(EMIT):
-                dst, slots, valid, remaining, b = compact_gather(
-                    cfg, friends, friend_cnt, dslot, delay_key, drop_key,
-                    tick, remaining, cap)
-            return deposit_local(pending, dst, slots, valid, kernel=dkern,
-                                 n=n), remaining, blk + b
+    with jax.named_scope(EMIT):
+        plan = chunk_plan(senders, cap)
+    chunks = (plan.count + cap - 1) // cap
 
-        pending, _, blk = jax.lax.fori_loop(
-            0, chunks, body_p, (pending, senders, jnp.zeros((), I32)))
-        return pending, blk
-
-    def body(_, carry):
-        pending, remaining = carry
+    def body(j, carry):
+        pending, cursor, blk = carry
         with jax.named_scope(EMIT):
-            dst, slots, valid, remaining, _ = compact_gather(
+            idx, cursor = plan_chunk(plan, j, cursor)
+            dst, slots, valid, b = compact_gather(
                 cfg, friends, friend_cnt, dslot, delay_key, drop_key, tick,
-                remaining, cap)
+                idx)
         return deposit_local(pending, dst, slots, valid, kernel=dkern,
-                             n=n), remaining
+                             n=n), cursor, blk + b
 
-    pending, _ = jax.lax.fori_loop(0, chunks, body, (pending, senders))
-    return pending, 0
+    zero = jnp.zeros((), I32)
+    pending, _, blk = jax.lax.fori_loop(0, chunks, body,
+                                        (pending, zero, zero))
+    return pending, (blk if cfg.scenario_resolved.has_partitions else 0)
 
 
 def deposit_local(pending, dst_local, slots, valid, *, n: int,

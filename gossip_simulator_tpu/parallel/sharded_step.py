@@ -36,6 +36,7 @@ from gossip_simulator_tpu.models import epidemic, graphs, overlay
 from gossip_simulator_tpu.models import state as state_mod
 from gossip_simulator_tpu.models.state import (OverlayState, SimState,
                                                msg64_add)
+from gossip_simulator_tpu.ops import select
 from gossip_simulator_tpu.ops.mailbox import deliver
 from gossip_simulator_tpu.parallel import exchange
 from gossip_simulator_tpu.parallel.mesh import AXIS, shard_size
@@ -129,7 +130,7 @@ def _route_stage_si(cfg: Config, n_local: int, n_shards: int, dst_global,
     caller deposits the barrier-threaded PREVIOUS stage while this
     chunk's all_to_all is in flight.  Deferring the deposit is
     trivially bit-identical here: nothing in the chunk loop reads
-    `pending` (compact_gather keys off friends/dslot/remaining only),
+    `pending` (the chunk's rows come from the tick's select.chunk_plan),
     and deposits replay in the serial FIFO order.  Returns
     (stage_new, overflow, pstage_threaded[, exch])."""
     d = epidemic.ring_depth(cfg)
@@ -199,12 +200,6 @@ def make_sharded_tick(cfg: Config, mesh):
         ovf0 = exchange.ovf_join(jnp.zeros((), I32),
                                  st.exch_counts if spatial else None)
 
-        def gather(remaining, ccap, **kw):
-            with jax.named_scope(epidemic.EMIT):
-                return epidemic.compact_gather(
-                    cfg, stp.friends, stp.friend_cnt, dslot, keys["delay"],
-                    keys["drop"], st.tick, remaining, ccap, **kw)
-
         if cfg.compact_resolved:
             # The chunk loop is DELIVER, with EMIT (the gathers) and ROUTE
             # (the exchanges) inside it, as in epidemic.make_tick_fn.
@@ -224,73 +219,81 @@ def make_sharded_tick(cfg: Config, mesh):
             else:
                 rcap = min(exchange.epidemic_cap(n_local, width, s),
                            ccap * width)
+            with jax.named_scope(epidemic.EMIT):
+                plan = select.chunk_plan(senders, ccap)
             with jax.named_scope(epidemic.DELIVER):
-                count = jax.lax.pmax(senders.sum(dtype=I32), AXIS)
+                count = jax.lax.pmax(plan.count, AXIS)
                 chunks = (count + ccap - 1) // ccap
+
+            def gather(j, cursor, **kw):
+                with jax.named_scope(epidemic.EMIT):
+                    idx, cursor = select.plan_chunk(plan, j, cursor)
+                    return (*epidemic.compact_gather(
+                        cfg, stp.friends, stp.friend_cnt, dslot,
+                        keys["delay"], keys["drop"], st.tick, idx, **kw),
+                        cursor)
 
             if pipe:
                 # Pipelined chunk loop (-exchange-pipeline double): chunk
                 # j's deposit flushes behind chunk j+1's in-flight
                 # collective (_route_stage_si's identity note); the last
                 # stage flushes after the loop.
-                def body_pipe(_, carry):
-                    pending, remaining, ovf, blk, pend = carry
+                def body_pipe(j, carry):
+                    pending, cursor, ovf, blk, pend = carry
                     oacc, exch = exchange.ovf_split(ovf)
-                    (dstg, slots, valid, remaining, b2) = gather(
-                        remaining, ccap,
-                        **(dict(gid0=gid0) if track_part else {}))
+                    (dstg, slots, valid, b2, cursor) = gather(
+                        j, cursor, **(dict(gid0=gid0) if track_part else {}))
                     out = _route_stage_si(
                         cfg, n_local, s, dstg, slots, valid, rcap, pend,
                         exch=exch)
                     (nstage, o, pthr), exch = out[:3], (
                         out[3] if exch is not None else None)
                     pending = _flush_deposit(cfg, n_local, pending, pthr)
-                    return (pending, remaining,
+                    return (pending, cursor,
                             exchange.ovf_join(oacc + o, exch),
                             blk + (b2 if track_part else 0), nstage)
 
                 with jax.named_scope(epidemic.DELIVER):
                     pending, _, ovf, blk, pend = jax.lax.fori_loop(
                         0, chunks, body_pipe,
-                        (stp.pending, senders, ovf0, zblk,
+                        (stp.pending, zblk, ovf0, zblk,
                          _empty_deposit_stage(s * rcap)))
                 pending = _flush_deposit(cfg, n_local, pending, pend)
             elif track_part:
-                def body_p(_, carry):
-                    pending, remaining, ovf, blk = carry
+                def body_p(j, carry):
+                    pending, cursor, ovf, blk = carry
                     oacc, exch = exchange.ovf_split(ovf)
-                    (dstg, slots, valid, remaining, b2) = gather(
-                        remaining, ccap, gid0=gid0)
+                    (dstg, slots, valid, b2, cursor) = gather(
+                        j, cursor, gid0=gid0)
                     out = _deposit_routed(cfg, n_local, s, pending,
                                           dstg, slots, valid, rcap,
                                           exch=exch)
                     (pending, o), exch = out[:2], (
                         out[2] if exch is not None else None)
-                    return (pending, remaining,
+                    return (pending, cursor,
                             exchange.ovf_join(oacc + o, exch), blk + b2)
 
                 with jax.named_scope(epidemic.DELIVER):
                     pending, _, ovf, blk = jax.lax.fori_loop(
                         0, chunks, body_p,
-                        (stp.pending, senders, ovf0, zblk))
+                        (stp.pending, zblk, ovf0, zblk))
             else:
-                def body(_, carry):
-                    pending, remaining, ovf = carry
+                def body(j, carry):
+                    pending, cursor, ovf = carry
                     oacc, exch = exchange.ovf_split(ovf)
-                    (dstg, slots, valid, remaining, _b) = gather(
-                        remaining, ccap)
+                    (dstg, slots, valid, _b, cursor) = gather(j, cursor)
                     out = _deposit_routed(cfg, n_local, s, pending,
                                           dstg, slots, valid, rcap,
                                           exch=exch)
                     (pending, o), exch = out[:2], (
                         out[2] if exch is not None else None)
-                    return (pending, remaining,
+                    return (pending, cursor,
                             exchange.ovf_join(oacc + o, exch))
 
                 with jax.named_scope(epidemic.DELIVER):
                     pending, _, ovf = jax.lax.fori_loop(
                         0, chunks, body,
-                        (stp.pending, senders, ovf0))
+                        (stp.pending, zblk, ovf0))
                 blk = zblk
         else:
             with jax.named_scope(epidemic.EMIT):
@@ -539,11 +542,14 @@ def make_sharded_seed(cfg: Config, mesh):
         # Only the sender's row emits: gather it (row-keyed draws, so the
         # same edges as a dense pass over every row) and route its k
         # lanes.  A dense [n_local, k] wave did not fit a chip's HBM at
-        # 25M rows per shard.
+        # 25M rows per shard.  Shards without the sender gather fill row
+        # n_local, whose edges are invalid.
         k = st.friends.shape[1]
-        dst, slots, valid, _, blk = epidemic.compact_gather(
+        row = sender - shard * n_local
+        row = jnp.where((row >= 0) & (row < n_local), row, n_local)
+        dst, slots, valid, blk = epidemic.compact_gather(
             cfg, st.friends, st.friend_cnt, dslot, kd, kp, st.tick,
-            is_sender, 1, gid0=shard * n_local)
+            row[None], gid0=shard * n_local)
         if cfg.scenario_resolved.has_partitions:
             st = st._replace(part_dropped=st.part_dropped
                              + jax.lax.psum(blk, AXIS))

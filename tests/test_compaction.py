@@ -55,20 +55,10 @@ def test_multi_chunk_identical_jax():
 
 
 CASES = ["sparse", "clustered", "dense", "empty", "all", "tail", "head"]
+GRID = [(5000, 64), (5000, 4999), (20000, 777), (8192, 256)]
 
 
-@pytest.mark.parametrize("case", CASES)
-@pytest.mark.parametrize("n,cap", [(5000, 64), (5000, 4999), (20000, 777),
-                                   (8192, 256)])
-def test_first_true_indices_two_level(case, n, cap):
-    """The two-level block path (taken at n > 4096) must match
-    jnp.nonzero(size=cap, fill_value=n) exactly -- first <=cap True indices
-    ascending, padded with n.  Covers the production path bench runs at
-    n=1e7, which the simulation tests (small n) never reach."""
-    import jax.numpy as jnp
-
-    from gossip_simulator_tpu.models.epidemic import first_true_indices
-
+def _case_mask(case, n, cap):
     rng = np.random.default_rng((CASES.index(case) + 1) * 1_000_003 + n + cap)
     mask = np.zeros(n, bool)
     if case == "sparse":
@@ -83,10 +73,58 @@ def test_first_true_indices_two_level(case, n, cap):
         mask[-5:] = True
     elif case == "head":
         mask[:5] = True
+    return mask
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("n,cap", GRID)
+def test_first_true_indices_two_level(case, n, cap):
+    """The two-level block path (taken at n > 4096) must match
+    jnp.nonzero(size=cap, fill_value=n) exactly -- first <=cap True indices
+    ascending, padded with n.  Covers the production path bench runs at
+    n=1e7, which the simulation tests (small n) never reach."""
+    import jax.numpy as jnp
+
+    from gossip_simulator_tpu.models.epidemic import first_true_indices
+
+    mask = _case_mask(case, n, cap)
     got = np.asarray(first_true_indices(jnp.asarray(mask), cap))
     want = np.asarray(
         jnp.nonzero(jnp.asarray(mask), size=cap, fill_value=n)[0])
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("n,cap", GRID + [
+    (20003, 777),  # n % 32 != 0: a partial last word
+    (3000, 64),    # n <= 4096, where first_true_indices takes nonzero
+    (6000, 6000),  # cap >= n: one chunk takes every row
+])
+def test_chunk_plan_matches_nonzero(case, n, cap):
+    """select.chunk_plan ranks a tick's senders once; chunk j of
+    plan_chunk must be exactly the Trues of rank [j*cap, (j+1)*cap) in
+    ascending order, padded with n -- the rows the per-chunk
+    first_true_indices loop took (so the row-keyed draws, the deposit
+    order and the sharded route's buckets are unchanged).  Walks every
+    chunk a mask of n rows can need, past the last partial one: the
+    sharded loop runs the mesh's largest chunk count on every shard."""
+    import jax
+    import jax.numpy as jnp
+
+    from gossip_simulator_tpu.ops.select import chunk_plan, plan_chunk
+
+    mask = _case_mask(case, n, cap)
+    want = np.nonzero(mask)[0]
+    plan = chunk_plan(jnp.asarray(mask), cap)
+    assert int(plan.count) == want.size
+    take = jax.jit(lambda j, c: plan_chunk(plan, j, c))
+    cursor = jnp.zeros((), jnp.int32)
+    for j in range(-(-n // cap)):
+        got, cursor = take(j, cursor)
+        rows = want[j * cap:(j + 1) * cap]
+        np.testing.assert_array_equal(
+            np.asarray(got), np.pad(rows, (0, cap - rows.size),
+                                    constant_values=n), err_msg=f"chunk {j}")
 
 
 def test_multi_chunk_identical_sharded():

@@ -19,6 +19,9 @@ step that does not fit HBM, a mesh program without its collective.
   mesh: each shard's flat delay ring is written in place, as on one chip.
 * The sharded ring engine's seed program at 25M rows per shard, the
   north-star cell's, within the chip's HBM.
+* The ring engine's compaction chunk loop, on one chip and at S=4: it
+  carries no bool[n] mask and computes nothing sized by n, the tick
+  having ranked its senders before the loop.
 
 The topology is described only inside the module fixture below, never
 while a module is imported (one process at a time may load libtpu), and
@@ -195,6 +198,82 @@ def test_ring_run_scatters_into_the_ring_in_place(one_chip, no_cache):
     assert temp < 4 * ring, temp
 
 
+# Ops that only move an array the loop already holds (into another memory
+# space, in slices): the compiler's choice, not work sized by the mask.
+_MOVES = ("parameter", "get-tuple-element", "tuple", "bitcast",
+          "copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def _chunk_loop(hlo_text, ring):
+    """The chunk loop of a ring-engine program: the innermost while loop
+    that carries the flat ring.  Returns (dtype, elements) of every array
+    in its carry, and (op, name, dtype, elements) of every array its body
+    computes, leaving out ring-sized arrays (the ring's in-place scatter)
+    and the ops in _MOVES."""
+    import re
+
+    def arrays(shape):
+        return [(dt, int(np.prod([int(x) for x in dims.split(",")])))
+                for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]+)\]",
+                                           shape)]
+
+    comps = dict((m.group(1), m.group(2)) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) .*?\{\n(.*?)^\}", hlo_text, re.M | re.S))
+    inst = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.+?) ([a-z][\w-]*)\(")
+
+    def ring_loops(body):
+        out = []
+        for line in body.splitlines():
+            m = inst.match(line)
+            if m and m.group(3) == "while" and (
+                    "s32", ring) in arrays(m.group(2)):
+                out.append((m.group(2), re.search(r"body=%([^,\s]+)",
+                                                  line).group(1)))
+        return out
+
+    loops = [lp for body in comps.values() for lp in ring_loops(body)]
+    inner = [lp for lp in loops if not ring_loops(comps[lp[1]])]
+    assert len(inner) == 1, inner
+    carry, body = inner[0]
+    made = []
+    for line in comps[body].splitlines():
+        m = inst.match(line)
+        if not m or m.group(3) in _MOVES or "ConcatBitcast" in line:
+            continue
+        made += [(m.group(3), m.group(1), dt, e)
+                 for dt, e in arrays(m.group(2)) if e != ring]
+    return arrays(carry), made
+
+
+def _assert_chunk_loop_sized_by_cap(hlo_text, ring, n):
+    """Before the tick ranked its senders once, each chunk carried the
+    bool[n] remaining mask, counted n/16 blocks of it and scattered a
+    bool[n] hit mask to clear the chunk's rows."""
+    carry, made = _chunk_loop(hlo_text, ring)
+    masks = [a for a in carry if a[0] == "pred" and a[1] >= n // 16]
+    assert not masks, masks
+    big = [a for a in made if a[3] >= n // 16]
+    assert not big, big
+
+
+def test_ring_chunk_loop_is_sized_by_the_chunk(one_chip, no_cache):
+    """The ring engine's run program at 1M rows (the default chunk,
+    n/128): the chunk loop computes only arrays of the chunk's size."""
+    from gossip_simulator_tpu.models import epidemic, graphs
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    cfg = parse_args(["-n", str(ROWS), "-backend", "jax", "-engine", "ring",
+                      *SI_FLAGS])
+    state = jax.eval_shape(lambda: epidemic.init_state(
+        cfg, *graphs.generate(cfg, graphs.graph_key(cfg))))
+    key = jax.eval_shape(lambda: _rng.base_key(cfg.seed))
+    count = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    compiled = epidemic.make_run_to_coverage_fn(cfg).lower(
+        _on(state, one_chip), _on(key, one_chip), count, count).compile()
+    _assert_chunk_loop_sized_by_cap(
+        compiled.as_text(), epidemic.ring_depth(cfg) * ROWS, ROWS)
+
+
 def test_sharded_window_step_s4_has_all_to_all(topo, no_cache):
     from gossip_simulator_tpu.parallel import event_sharded
     from gossip_simulator_tpu.parallel.mesh import AXIS
@@ -251,6 +330,38 @@ def test_sharded_ring_run_scatters_into_the_ring_in_place(topo, no_cache):
     updates = [name for op, name, c in ops
                if op == "dynamic-update-slice" and c]
     assert len(updates) == 1, updates
+
+
+def test_sharded_ring_chunk_loop_is_sized_by_the_chunk(topo, no_cache):
+    """The sharded ring engine's run program (pipelined exchange) on the
+    described 2x2 mesh at 1M rows per shard: each shard's chunk loop
+    computes only arrays of the chunk's size.  The chunk is 2048 rows
+    here, not the default n/128, so that the route's own per-chunk
+    arrays (S x chunk x fanout lanes) stay under n/16 and every array
+    sized by n stands out."""
+    import dataclasses
+
+    from gossip_simulator_tpu.models import epidemic
+    from gossip_simulator_tpu.parallel import sharded_step
+    from gossip_simulator_tpu.parallel.mesh import AXIS
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    cfg = dataclasses.replace(parse_args(
+        ["-n", str(4 * ROWS), "-backend", "sharded", "-engine", "ring",
+         "-exchange-pipeline", "double", *SI_FLAGS]), compact_chunk=2048)
+    state = jax.eval_shape(sharded_step.make_sharded_init(cfg, mesh))
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        state, sharded_step.sim_state_specs(cfg))
+    rep = NamedSharding(mesh, PartitionSpec())
+    key = _on(jax.eval_shape(lambda: _rng.base_key(cfg.seed)), rep)
+    count = jax.ShapeDtypeStruct((), I32, sharding=rep)
+    compiled = sharded_step.make_run_to_coverage_fn(cfg, mesh).lower(
+        state, key, count, count).compile()
+    _assert_chunk_loop_sized_by_cap(
+        compiled.as_text(), epidemic.ring_depth(cfg) * ROWS, ROWS)
 
 
 def test_sharded_ring_seed_fits_hbm_at_the_north_star_shard(topo, no_cache):
