@@ -196,27 +196,12 @@ QUEUED_HARDWARE_ROWS = (
              "(the traffic matrix + shard/group panels' recording cost "
              "over real ICI; the CPU spatial_overhead_1m twin bounds "
              "only the single-chip scatter cost)"},
-    {"row": "megakernel_50m_twins", "queued_since": "r18",
-     "capture": "capture_megakernel_twins",
-     "what": "50M -phase2-kernel xla-vs-pallas same-seed wall-clock "
-             "twins (event, R=16, pushsum), each reported as ns/message "
-             "against ROOFLINE.json's per-term floor (the fused pass is "
-             "parity-pinned bit-identical on CPU but unmeasured on "
-             "device)"},
     {"row": "hostloss_50m_twins", "queued_since": "r20",
      "capture": "capture_hostloss_50m",
      "what": "50M supervised kill-drill vs undisturbed same-seed twin "
              "(recovery_pause_ms against a real-scale snapshot + "
              "Stats-exactness at scale; the CPU hostloss_recovery row "
              "bounds only the /100 stand-in restore)"},
-    {"row": "phase1_kernel_100m_twins", "queued_since": "r19",
-     "capture": "capture_phase1_kernel_twins",
-     "what": "100M two-phase -phase1-kernel xla-vs-pallas same-seed "
-             "twins (plus the 50M rounds/ticks pair), each reported as "
-             "overlay ns/round against ROOFLINE.json's phase-1 "
-             "per-node-slot floor; target: within 4x of phase1_total_ns "
-             "(the fused negotiation is parity-pinned bit-identical on "
-             "CPU but unmeasured on device)"},
 )
 
 
@@ -1032,171 +1017,6 @@ def capture_deliver_kernel_twins(detail: dict, seed: int) -> None:
             detail[f"{name}_{kern}"] = row
 
 
-def capture_megakernel_twins(detail: dict, seed: int) -> None:
-    """-phase2-kernel A/B twins at scale (ISSUE 18): the 50M suite shape,
-    its R=16 sibling, and the 50M pushsum shape, each run with the fused
-    emit->route->deliver megakernel vs the XLA chain it replaces at the
-    SAME n/graph/seed.  Interpret-mode CI already pins bit-identical
-    trajectories (tests/test_megakernel.py), so these rows exist to
-    record the measured wall-clock delta AND the achieved ns/message
-    against ROOFLINE.json's per-term floor; an unreachable device
-    leaves dated skip records that re-queue the pair."""
-    base = Config(n=50_000_000, fanout=6, graph="kout", backend="jax",
-                  seed=seed, crashrate=0.0, coverage_target=0.95,
-                  max_rounds=3000, progress=False).validate()
-    push = Config(n=50_000_000, fanout=6, graph="kout", backend="jax",
-                  seed=seed, crashrate=0.0, droprate=0.0, model="pushsum",
-                  coverage_target=0.9, max_rounds=3000,
-                  progress=False).validate()
-    for name, cfg in (("megakernel_50m", base),
-                      ("megakernel_50m_r16", base.replace(rumors=16)),
-                      ("megakernel_50m_pushsum", push)):
-        for kern in ("xla", "pallas"):
-            row = pool_retry(
-                _bench_backend,
-                cfg.replace(phase2_kernel=kern).validate(),
-                name=f"{name}_{kern}")
-            detail[f"{name}_{kern}"] = row
-
-
-def capture_megakernel_interpret_parity(detail: dict, seed: int) -> None:
-    """Measured CPU-scale -phase2-kernel twin (ISSUE 18): interpret mode
-    is the correctness surface, not a fast path, so this row records the
-    measured cost of that surface next to a live trajectory-equality
-    verdict -- the bench sibling of ROOFLINE.json's interpret evidence
-    row.  The speed question stays queued (megakernel_50m_twins)."""
-    import hashlib
-
-    from gossip_simulator_tpu.backends import make_stepper
-
-    base = Config(n=2_000, fanout=6, graph="kout", backend="jax",
-                  seed=seed, crashrate=0.01, coverage_target=0.95,
-                  max_rounds=3000, progress=False).validate()
-
-    def run(cfg):
-        s = make_stepper(cfg)
-        s.init()
-        while not s.overlay_window()[2]:
-            pass
-        s.seed()
-        rows = []
-        t0 = time.perf_counter()
-        for _ in range(400):
-            st = s.gossip_window()
-            rows.append((st.round, st.total_received, st.total_message,
-                         st.total_crashed, st.total_removed))
-            if st.coverage >= cfg.coverage_target or s.exhausted:
-                break
-        wall = time.perf_counter() - t0
-        fp = hashlib.sha256(
-            json.dumps(rows).encode()).hexdigest()[:16]
-        return wall, int(st.total_message), fp
-
-    xw, xm, xfp = run(base.replace(phase2_kernel="xla").validate())
-    pw, pm, pfp = run(base.replace(phase2_kernel="pallas").validate())
-    detail["megakernel_interpret_parity"] = {
-        "n": base.n, "mode": "interpret",
-        "xla_s": xw, "pallas_s": pw,
-        "xla_ns_per_message": xw / max(1, xm) * 1e9,
-        "pallas_ns_per_message": pw / max(1, pm) * 1e9,
-        "trajectory_match": xfp == pfp, "fingerprint": xfp,
-    }
-
-
-def capture_phase1_kernel_twins(detail: dict, seed: int) -> None:
-    """-phase1-kernel A/B twins at scale (ISSUE 19): the 100M two-phase
-    flagship shape (rounds mode, the auto split-round memory path whose
-    hosted delivery also exercises the fused occupancy pass) plus a 50M
-    rounds/ticks pair, each run with the fused negotiate/request kernels
-    vs the one-hot XLA chain at the SAME n/graph/seed.  Interpret-mode
-    CI already pins bit-identical trajectories
-    (tests/test_overlay_kernel.py), so these rows exist to record the
-    measured overlay wall-clock delta AND the achieved ns/round against
-    ROOFLINE.json's phase1 per-node-slot floor; an unreachable device
-    leaves dated skip records that re-queue the pair."""
-    from gossip_simulator_tpu.driver import run_simulation
-    from gossip_simulator_tpu.utils.metrics import ProgressPrinter
-
-    star = Config(n=100_000_000, graph="overlay", fanout=5, seed=seed,
-                  coverage_target=0.90, backend="jax",
-                  progress=False).validate()
-    mid = star.replace(n=50_000_000)
-    rows = [("phase1_100m", star), ("phase1_50m", mid),
-            ("phase1_50m_ticks", mid.replace(overlay_mode="ticks"))]
-
-    def _run(cfg):
-        t0 = time.perf_counter()
-        with ProgressPrinter(False) as printer:
-            res = run_simulation(cfg, printer=printer)
-        return {
-            "n": cfg.n, "overlay_mode": cfg.overlay_mode_resolved,
-            "phase1_kernel": cfg.phase1_kernel_resolved,
-            "overlay_windows": res.overlay_windows,
-            "stabilize_sim_ms": res.stabilize_ms,
-            "overlay_ns_per_round": (
-                (time.perf_counter() - t0) * 1e9
-                / max(1, res.overlay_windows)),
-            "coverage": res.stats.coverage,
-            "wall_s": round(time.perf_counter() - t0, 1),
-        }
-
-    for name, cfg in rows:
-        for kern in ("xla", "pallas"):
-            row = pool_retry(
-                _run, cfg.replace(phase1_kernel=kern).validate(),
-                name=f"{name}_{kern}")
-            detail[f"{name}_{kern}"] = row
-
-
-def capture_phase1_interpret_parity(detail: dict, seed: int) -> None:
-    """Measured CPU-scale -phase1-kernel twin (ISSUE 19): interpret mode
-    is the correctness surface, not a fast path, so this row records the
-    measured overlay cost of that surface next to a live
-    trajectory-equality verdict -- the bench sibling of ROOFLINE.json's
-    pallas_overlay_kernel interpret evidence row.  The speed question
-    stays queued (phase1_kernel_100m_twins)."""
-    import hashlib
-
-    from gossip_simulator_tpu.backends import make_stepper
-
-    base = Config(n=2_000, graph="overlay", overlay_mode="rounds",
-                  fanout=5, seed=seed, backend="jax",
-                  coverage_target=0.9, progress=False).validate()
-
-    def run(cfg):
-        s = make_stepper(cfg)
-        s.init()
-        rows = []
-        t0 = time.perf_counter()
-        windows = 0
-        for _ in range(3000):
-            mk, bk, q = s.overlay_window()
-            rows.append((mk, bk))
-            windows += 1
-            if q:
-                break
-        overlay_wall = time.perf_counter() - t0
-        s.seed()
-        for _ in range(400):
-            st = s.gossip_window()
-            rows.append((st.round, st.total_received, st.total_message,
-                         st.total_crashed, st.total_removed))
-            if st.coverage >= cfg.coverage_target or s.exhausted:
-                break
-        fp = hashlib.sha256(json.dumps(rows).encode()).hexdigest()[:16]
-        return overlay_wall, windows, fp
-
-    xw, xn, xfp = run(base.replace(phase1_kernel="xla").validate())
-    pw, pn, pfp = run(base.replace(phase1_kernel="pallas").validate())
-    detail["phase1_interpret_parity"] = {
-        "n": base.n, "mode": "interpret",
-        "xla_overlay_s": xw, "pallas_overlay_s": pw,
-        "xla_ns_per_round": xw / max(1, xn) * 1e9,
-        "pallas_ns_per_round": pw / max(1, pn) * 1e9,
-        "trajectory_match": xfp == pfp, "fingerprint": xfp,
-    }
-
-
 def capture_exchange_pipeline_twins(detail: dict, seed: int) -> None:
     """-exchange-pipeline A/B twins at scale (ISSUE 13): the 50M suite
     shape on the sharded backend (S = all attached chips), run with the
@@ -1295,9 +1115,8 @@ def _pallas_validation() -> dict:
         spec.loader.exec_module(mod)
         result = mod.run_checks()
         result["deliver_tpu"] = mod.run_deliver_checks()
-        result["megakernel_tpu"] = mod.run_megakernel_checks()
-        # Merge, don't overwrite: the artifact also carries the dated
-        # CPU --interpret deliver/megakernel verdicts from CI hosts.
+        # Merge, don't overwrite: the artifact also carries the CPU
+        # --interpret deliver verdict from CI hosts.
         mod._merge_out(os.path.join(here, "PALLAS_VALIDATION.json"), result)
         return result
     except Exception as e:  # record, don't kill the bench line
@@ -1504,12 +1323,6 @@ def main() -> int:
         # Spatial-telemetry on/off twins (ISSUE 16): panels must cost
         # <= 5% wall clock and leave the trajectory untouched.
         capture_spatial_overhead(result["detail"], args.seed)
-        # -phase2-kernel interpret-mode parity twin (ISSUE 18): measured
-        # cost of the CPU correctness surface + live trajectory match.
-        capture_megakernel_interpret_parity(result["detail"], args.seed)
-        # -phase1-kernel interpret-mode parity twin (ISSUE 19): measured
-        # overlay cost of the CPU correctness surface + live match.
-        capture_phase1_interpret_parity(result["detail"], args.seed)
         if jax.default_backend() == "tpu":
             # Distributional validation of the Pallas generators on real
             # hardware (interpret-mode CI can only check structure); also
@@ -1541,12 +1354,6 @@ def main() -> int:
             # -deliver-kernel fused-vs-XLA wall-clock twins at 50M/100M
             # (ISSUE 9; dated skips re-queue when the pool is down).
             capture_deliver_kernel_twins(result["detail"], args.seed)
-            # -phase2-kernel megakernel-vs-XLA twins at 50M (ISSUE 18):
-            # ns/message lands against ROOFLINE.json's per-term floor.
-            capture_megakernel_twins(result["detail"], args.seed)
-            # -phase1-kernel overlay-vs-XLA twins at 100M/50M (ISSUE 19):
-            # ns/round lands against ROOFLINE.json's phase-1 floor.
-            capture_phase1_kernel_twins(result["detail"], args.seed)
             # 50M sharded exchange-pipeline double-vs-off twins
             # (ISSUE 13): the overlap win needs real ICI to show.
             capture_exchange_pipeline_twins(result["detail"], args.seed)

@@ -132,11 +132,6 @@ class ShardedStepper(Stepper):
                 # sequence cannot run per shard); per-shard slices at
                 # memory scale can hit the fused-round OOM class the
                 # single-device split exists to avoid (advisor r4).
-                # -phase1-kernel threads through cfg into the per-shard
-                # round body (overlay.phase1_slot_fns), so the fused
-                # negotiate passes shrink the slot loop's temp set here
-                # too -- but they do not change the mailbox allocations
-                # this band is about.
                 import warnings
 
                 warnings.warn(

@@ -206,30 +206,6 @@ class Config:
     # chain and reproduces every prior trajectory bit-for-bit; "auto" is
     # xla on every platform (Config._kernel_gate).
     deliver_kernel: str = "auto"
-    # Phase-2 megakernel for the emit->route->deliver window (ROADMAP
-    # item 5, against the committed ROOFLINE.json floors): "pallas" runs
-    # the fused single-pass kernels (ops/pallas_megakernel -- emission
-    # mask/prefix/scatter, sharded receive landing, pushsum whole-slot
-    # drain, joint multi-rumor deposit; natively on TPU, interpret mode
-    # elsewhere; bit-identical, A/B-pinned by trajectory fingerprints);
-    # "xla" is the recorded multi-op chain and reproduces every prior
-    # trajectory bit-for-bit; "auto" is xla (Config._kernel_gate).
-    # Orthogonal to -deliver-kernel: where the megakernel engages it
-    # subsumes that gate's fused ops; everywhere else -deliver-kernel
-    # still applies.
-    phase2_kernel: str = "auto"
-    # Phase-1 overlay megakernel for the request->negotiate->reply chain
-    # (against ROOFLINE.json's phase-1 terms): "pallas" runs the fused
-    # single-pass kernels (ops/pallas_overlay_kernel -- slot negotiation
-    # with its decision masks/draw blends/reply emission in-register,
-    # bootstrap request append with write-time dead-skip counts, hosted
-    # ladder occupancy; natively on TPU, interpret mode elsewhere;
-    # bit-identical, A/B-pinned by trajectory fingerprints); "xla" is the
-    # recorded one-hot op chain and reproduces every prior trajectory
-    # bit-for-bit; "auto" is xla (Config._kernel_gate).  Orthogonal to
-    # -deliver-kernel: the delivery chain keeps its own gate; this one
-    # owns the negotiation passes around it.
-    phase1_kernel: str = "auto"
     # Exchange pipelining for the sharded backend (ROADMAP item 1):
     # "double" software-pipelines the per-chunk all_to_all at chunk
     # granularity -- the ring_append drain of batch j is deferred one
@@ -624,25 +600,17 @@ class Config:
 
     @staticmethod
     def _kernel_gate(value: str) -> str:
-        """"xla" or "pallas" for a -deliver-kernel / -phase2-kernel /
-        -phase1-kernel value.  "auto" is "xla" on every platform, with no
-        probe: whether a Pallas family goes back on the default path is
-        for a measured PR to decide.  Explicit "pallas" runs the kernels
-        (natively on TPU, in interpret mode elsewhere) and lets whatever
-        the compiler or the interpreter raises propagate."""
+        """"xla" or "pallas" for a -deliver-kernel value.  "auto" is "xla"
+        on every platform, with no probe: whether the Pallas family goes
+        back on the default path is for a measured PR to decide.
+        Explicit "pallas" runs the kernels (natively on TPU, in interpret
+        mode elsewhere) and lets whatever the compiler or the interpreter
+        raises propagate."""
         return "pallas" if value == "pallas" else "xla"
 
     @property
     def deliver_kernel_resolved(self) -> str:
         return self._kernel_gate(self.deliver_kernel)
-
-    @property
-    def phase2_kernel_resolved(self) -> str:
-        return self._kernel_gate(self.phase2_kernel)
-
-    @property
-    def phase1_kernel_resolved(self) -> str:
-        return self._kernel_gate(self.phase1_kernel)
 
     @property
     def exchange_pipeline_resolved(self) -> str:
@@ -697,8 +665,6 @@ class Config:
         }
         jaxy = self.backend in ("jax", "sharded")
         gates["deliver_kernel"] = self.deliver_kernel_resolved if jaxy else None
-        gates["phase2_kernel"] = self.phase2_kernel_resolved if jaxy else None
-        gates["phase1_kernel"] = self.phase1_kernel_resolved if jaxy else None
         # Exchange pipelining only exists on the sharded backend's
         # routed path; everywhere else there is no exchange to overlap.
         gates["exchange_pipeline"] = (
@@ -909,14 +875,6 @@ class Config:
             raise ValueError(
                 f"deliver_kernel must be auto|xla|pallas, "
                 f"got {self.deliver_kernel!r}")
-        if self.phase2_kernel not in ("auto", "xla", "pallas"):
-            raise ValueError(
-                f"phase2_kernel must be auto|xla|pallas, "
-                f"got {self.phase2_kernel!r}")
-        if self.phase1_kernel not in ("auto", "xla", "pallas"):
-            raise ValueError(
-                f"phase1_kernel must be auto|xla|pallas, "
-                f"got {self.phase1_kernel!r}")
         if self.exchange_pipeline not in ("auto", "off", "double"):
             raise ValueError(
                 f"exchange_pipeline must be auto|off|double, "
@@ -1433,23 +1391,6 @@ def _build_parser() -> argparse.ArgumentParser:
                         "sort/rank/scatter chain into one pass "
                         "(bit-identical, A/B-pinned); xla reproduces "
                         "prior trajectories bit-for-bit; auto = xla")
-    p.add_argument("-phase2-kernel", "--phase2-kernel",
-                   dest="phase2_kernel", choices=("auto", "xla", "pallas"),
-                   default=d.phase2_kernel,
-                   help="phase-2 megakernel: pallas fuses the "
-                        "emit/receive-land/drain chains into single "
-                        "passes against the ROOFLINE.json floors "
-                        "(bit-identical, A/B-pinned); xla reproduces "
-                        "prior trajectories bit-for-bit; auto = xla")
-    p.add_argument("-phase1-kernel", "--phase1-kernel",
-                   dest="phase1_kernel", choices=("auto", "xla", "pallas"),
-                   default=d.phase1_kernel,
-                   help="phase-1 overlay megakernel: pallas fuses the "
-                        "slot-negotiate/bootstrap-request/hosted-occupancy "
-                        "chains into single passes against the "
-                        "ROOFLINE.json phase-1 floors (bit-identical, "
-                        "A/B-pinned); xla reproduces prior trajectories "
-                        "bit-for-bit; auto = xla")
     p.add_argument("-exchange-pipeline", "--exchange-pipeline",
                    dest="exchange_pipeline",
                    choices=("auto", "off", "double"),

@@ -110,26 +110,6 @@ def _run(cfg: Config, printer: ProgressPrinter,
         # confirm both gates were exercised).
         printer.note(f"exchange-pipeline: {cfg.exchange_pipeline_resolved} "
                      f"(requested {cfg.exchange_pipeline})")
-    if cfg.backend in ("jax", "sharded") and cfg.phase2_kernel != "auto":
-        # Gated on an EXPLICIT request only: the default (auto on a CPU
-        # host) resolves to xla silently, keeping the golden transcripts
-        # byte-identical.  An explicit -phase2-kernel run's transcript
-        # records what actually compiled (and auto-on-TPU runs surface
-        # through resolved_gates in the result record).
-        try:
-            p2r = cfg.phase2_kernel_resolved
-        except ValueError:
-            p2r = "unavailable"
-        printer.note(f"phase2-kernel: {p2r} "
-                     f"(requested {cfg.phase2_kernel})")
-    if cfg.backend in ("jax", "sharded") and cfg.phase1_kernel != "auto":
-        # Same explicit-request gate as phase2-kernel above.
-        try:
-            p1r = cfg.phase1_kernel_resolved
-        except ValueError:
-            p1r = "unavailable"
-        printer.note(f"phase1-kernel: {p1r} "
-                     f"(requested {cfg.phase1_kernel})")
     t_init = time.perf_counter()
     with _trace.span("init", cat="phase"):
         stepper.init()

@@ -462,7 +462,6 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
     # are rare once delays spread the wave).
     multi = cfg.multi_rumor
     dkern = cfg.deliver_kernel_resolved
-    p2 = cfg.phase2_kernel_resolved if multi else "xla"
     if multi:
         target = int(math.ceil(cfg.coverage_target * cfg.n))
 
@@ -485,24 +484,11 @@ def make_tick_fn(cfg: Config) -> Callable[[SimState, jax.Array], SimState]:
                     cfg, stp.friends, stp.friend_cnt, senders, dslot,
                     keys["drop"], tick=st.tick)
             with jax.named_scope(DELIVER):
-                if p2 == "pallas":
-                    # Phase-2 megakernel: the counting add and the R-row
-                    # rumor add land at the shared (slot, dst) cell in ONE
-                    # joint pass (integer adds commute -> bit-identical to
-                    # the sequential pair below).
-                    from gossip_simulator_tpu.ops import pallas_megakernel \
-                        as mk
-                    pending, prum = mk.fused_deposit_both(
-                        stp.pending.reshape(-1, n), stp.pending_rumors, dst,
-                        slots, valid, newbits)
-                    pending = pending.reshape(stp.pending.shape)
-                    stp = stp._replace(pending_rumors=prum)
-                else:
-                    pending = deposit_local(stp.pending, dst, slots, valid,
-                                            kernel=dkern, n=n)
-                    stp = stp._replace(pending_rumors=deposit_rumors(
-                        stp.pending_rumors, dst, slots, valid, newbits,
-                        kernel=dkern))
+                pending = deposit_local(stp.pending, dst, slots, valid,
+                                        kernel=dkern, n=n)
+                stp = stp._replace(pending_rumors=deposit_rumors(
+                    stp.pending_rumors, dst, slots, valid, newbits,
+                    kernel=dkern))
             with jax.named_scope(COMBINE):
                 hit = (stp.rumor_recv >= target) & (stp.rumor_done < 0)
                 stp = stp._replace(rumor_done=jnp.where(

@@ -467,8 +467,7 @@ def _sender_keys(base_key, op: int, ticks, rows):
 def append_messages(cfg: Config, mail_ids, mail_cnt, dropped, sender_ids,
                     svalid, sticks, friends, friend_cnt, base_key,
                     strig=None, flags=None, gid0=0, swords=None,
-                    mail_words=None, kernel: str = "xla",
-                    phase2: str = "xla"):
+                    mail_words=None, kernel: str = "xla"):
     """Emit each sender's broadcast (k sends, ONE shared delay drawn at its
     delivery tick -- simulator.go:141-142) into the packed mail ring.
 
@@ -536,38 +535,6 @@ def append_messages(cfg: Config, mail_ids, mail_cnt, dropped, sender_ids,
     arrive = sticks + delay
     wslot = (arrive // b) % dw
     off = arrive % b
-    if phase2 == "pallas":
-        # Phase-2 megakernel: everything from the edge masks down --
-        # partition block, duplicate filter, reservation prefix and the
-        # dual-ring scatter -- as ONE serial pass
-        # (ops/pallas_megakernel.fused_emit; bit-identical, see its
-        # module docstring).  The RNG draws above stay on the XLA side
-        # so streams are untouched; the raw partition predicate is
-        # evaluated here (vectorized trig-free mask math) and ANDed
-        # in-register.
-        from gossip_simulator_tpu.ops import pallas_megakernel as mk
-        scen = cfg.scenario_resolved
-        pmask = None
-        if scen.has_partitions:
-            pmask = _scen.partition_blocked(
-                scen, cfg.n, sticks[:, None], (gid0 + rows)[:, None], sf)
-        out = mk.fused_emit(
-            mail_ids, mail_cnt, sf, drop, svalid, wslot, off,
-            dw=dw, cap=cap, b=b,
-            tb=(trigger_base(n, b) if strig is not None else None),
-            strig=strig, sender_ids=sender_ids, pmask=pmask,
-            flags=flags, received_bit=int(RECEIVED),
-            swords=swords, mail_words=mail_words)
-        if swords is not None:
-            mail_ids, adds, sup_adds, lost, blk, mail_words = out
-        else:
-            mail_ids, adds, sup_adds, lost, blk = out
-        blocked_n = blk if scen.has_partitions else 0
-        new_cnt = mail_cnt + adds[None, :]
-        if swords is not None:
-            return (mail_ids, new_cnt, dropped + lost, sup_adds,
-                    blocked_n, mail_words)
-        return mail_ids, new_cnt, dropped + lost, sup_adds, blocked_n
     edge = svalid[:, None] & ~drop & (sf >= 0)
     scen = cfg.scenario_resolved
     blocked_n = 0
@@ -1102,7 +1069,6 @@ def make_window_step_fn(cfg: Config, n_local: int | None = None):
         from gossip_simulator_tpu.ops.mailbox import ring_append
     # Resolved at BUILD time, outside the trace below.
     dkern = cfg.deliver_kernel_resolved
-    p2 = cfg.phase2_kernel_resolved
 
     def step_fn(st: EventState, base_key: jax.Array) -> EventState:
         n = st.flags.shape[0]
@@ -1248,8 +1214,7 @@ def make_window_step_fn(cfg: Config, n_local: int | None = None):
                                 cfg, amail_ids, amail_cnt, adropped,
                                 sids, svalid, stick2, st.friends,
                                 st.friend_cnt, base_key, swords=sw,
-                                mail_words=awords, kernel=dkern,
-                                phase2=p2)
+                                mail_words=awords, kernel=dkern)
                         else:
                             (amail_ids, amail_cnt, adropped, sa,
                              ablk) = append_messages(
@@ -1257,7 +1222,7 @@ def make_window_step_fn(cfg: Config, n_local: int | None = None):
                                 sids, svalid, stick2, st.friends,
                                 st.friend_cnt, base_key, strig=strig,
                                 flags=aflags if suppress else None,
-                                kernel=dkern, phase2=p2)
+                                kernel=dkern)
                         out = (aflags, amail_ids, amail_cnt,
                                asup + sa[None, :], adropped)
                         if track_part:
@@ -1314,15 +1279,13 @@ def make_window_step_fn(cfg: Config, n_local: int | None = None):
                     cfg, mail_ids, mail_cnt, dropped,
                     jnp.where(senders, ids_s, 0), senders, sticks,
                     st.friends, st.friend_cnt, base_key,
-                    swords=delta_w, mail_words=mail_words, kernel=dkern,
-                    phase2=p2)
+                    swords=delta_w, mail_words=mail_words, kernel=dkern)
             else:
                 mail_ids, mail_cnt, dropped, sa, blk = append_messages(
                     cfg, mail_ids, mail_cnt, dropped,
                     jnp.where(senders, ids_s, 0), senders, sticks,
                     st.friends, st.friend_cnt, base_key, strig=strig,
-                    flags=flags if suppress else None, kernel=dkern,
-                    phase2=p2)
+                    flags=flags if suppress else None, kernel=dkern)
             if track_part:
                 part = part + blk
             return pack((flags, mail_ids, mail_cnt, sup_cnt + sa[None, :],

@@ -338,49 +338,6 @@ def process_makeup_slot(fanin, friends, cnt, src, has, kk):
     return friends, cnt, victim, ev
 
 
-def process_breakup_slot_pallas(n, fanout, friends, cnt, src, has, ids, kk):
-    """process_breakup_slot via the fused phase-1 kernel
-    (ops/pallas_overlay_kernel.fused_negotiate): same signature, same
-    draw stream (randint_excluding computed XLA-side on the identical
-    key), same return contract.  The kernel's reply is already
-    where(rp, nf, -1) and nf >= 0 always, so the returned (nf, rp) pair
-    -- (reply, reply >= 0) -- reproduces the callers'
-    where(rp, nf, -1) / rp.sum() blends bit-for-bit."""
-    from gossip_simulator_tpu.ops import pallas_overlay_kernel as _pok
-    nf = _rng.randint_excluding(kk, n, (cnt.shape[0],), src, ids)
-    friends, cnt, reply = _pok.fused_negotiate(
-        friends, cnt, src, has, nf, kind="breakup", limit=fanout)
-    return friends, cnt, reply, reply >= 0
-
-
-def process_makeup_slot_pallas(fanin, friends, cnt, src, has, kk):
-    """process_makeup_slot via the fused phase-1 kernel.  The eviction
-    position is drawn with the PRE-append counts -- observably identical
-    to the XLA path's post-append draw because accept (has & under) and
-    evict (has & ~under) are disjoint per row and non-evicting rows'
-    draws never escape the where(ev, ...) blend.  Evicted victims are
-    in-range friends (>= 0), so (reply, reply >= 0) reproduces the
-    callers' where(ev, victim, -1) / ev.sum() blends bit-for-bit."""
-    from gossip_simulator_tpu.ops import pallas_overlay_kernel as _pok
-    vpos = jax.random.randint(kk, cnt.shape, 0, jnp.maximum(cnt, 1),
-                              dtype=I32)
-    friends, cnt, reply = _pok.fused_negotiate(
-        friends, cnt, src, has, vpos, kind="makeup", limit=fanin)
-    return friends, cnt, reply, reply >= 0
-
-
-def phase1_slot_fns(cfg: Config):
-    """(breakup_slot_fn, makeup_slot_fn) for cfg's -phase1-kernel gate --
-    the single seam both engines (make_round_fn here, overlay_ticks'
-    make_step_fn) and their sharded wrappers select through, so the gate
-    can never fork between them.  Resolving the gate here also surfaces
-    the explicit `-phase1-kernel pallas` unavailability error at model
-    BUILD time, not mid-trace."""
-    if cfg.phase1_kernel_resolved == "pallas":
-        return process_breakup_slot_pallas, process_makeup_slot_pallas
-    return process_breakup_slot, process_makeup_slot
-
-
 def heal_dead_friends(n_global: int, friends, friend_cnt, detected_global,
                       healer_ok, ids_global, heal_key):
     """Phase-2 re-entry of the bootstrap/needNewFriend draw
@@ -431,11 +388,6 @@ def make_round_fn(cfg: Config,
     k = cfg.max_degree
     fanout, fanin = cfg.fanout, cfg.fanin_resolved
     cap = cfg.mailbox_cap_for(n_rows if n_rows is not None else n)
-    # Phase-1 megakernel gate: swap the shared slot closures (and the
-    # bootstrap block below) for their fused forms.  Sharded callers pass
-    # the same cfg, so shard_map bodies inherit the gate automatically.
-    bk_slot_fn, mk_slot_fn = phase1_slot_fns(cfg)
-    p1_pallas = bk_slot_fn is process_breakup_slot_pallas
     # One-shot bootstrap (round 7): init_state staged the burst, so the
     # per-round bootstrap block is skipped -- must agree with init_state's
     # gate or the overlay would never bootstrap at all.
@@ -571,7 +523,7 @@ def make_round_fn(cfg: Config,
             has = src >= 0
             kk = jax.random.fold_in(
                 jax.random.fold_in(rkey, _rng.OP_REPLACE), slot)
-            friends, cnt, nf, rp = bk_slot_fn(
+            friends, cnt, nf, rp = process_breakup_slot(
                 n, fanout, friends, cnt, src, has, ids, kk)
             mk_em = mk_em.at[slot].set(jnp.where(rp, nf, -1))
             mk_cnt = mk_cnt.at[slot].set(rp.sum(dtype=I32))
@@ -630,7 +582,7 @@ def make_round_fn(cfg: Config,
             has = src >= 0
             kk = jax.random.fold_in(
                 jax.random.fold_in(rkey, _rng.OP_EVICT), slot)
-            friends, cnt, victim, ev = mk_slot_fn(
+            friends, cnt, victim, ev = process_makeup_slot(
                 fanin, friends, cnt, src, has, kk)
             bk_em = bk_em.at[slot].set(jnp.where(ev, victim, -1))
             bk_cnt = bk_cnt.at[slot].set(ev.sum(dtype=I32))
@@ -654,21 +606,12 @@ def make_round_fn(cfg: Config,
             kb = jax.random.fold_in(rkey, _rng.OP_BOOTSTRAP)
             w = jax.random.randint(kb, (n_local,), 0, n, dtype=I32)
             w = jnp.where(w == ids, (w + 1) % n, w)
-            if p1_pallas:
-                # Fused needNewFriend pass: append + emission + the
-                # write-time count in one traversal (the draw stays
-                # XLA-side above, so the stream is untouched).
-                from gossip_simulator_tpu.ops import \
-                    pallas_overlay_kernel as _pok
-                friends, cnt, boot_em, boot_cnt = _pok.fused_request_round(
-                    friends, cnt, w, fanout=fanout)
-            else:
-                under = cnt < fanout
-                appcol = jnp.minimum(cnt, k - 1)
-                friends = _masked_set(friends, rows, appcol, w, under)
-                cnt = cnt + under.astype(I32)
-                boot_em = jnp.where(under, w, -1)
-                boot_cnt = under.sum(dtype=I32)
+            under = cnt < fanout
+            appcol = jnp.minimum(cnt, k - 1)
+            friends = _masked_set(friends, rows, appcol, w, under)
+            cnt = cnt + under.astype(I32)
+            boot_em = jnp.where(under, w, -1)
+            boot_cnt = under.sum(dtype=I32)
 
         # Global reductions (psum when sharded): window counts feed both the
         # progress lines and the quiescence predicate, so they must be the
@@ -753,8 +696,7 @@ def make_split_round_fn(cfg: Config):
     sc_split = spill_cap_for(cfg, n)
     hosted_deliver = make_hosted_column_delivery(
         n, cap, hosted_chunk_widths(cfg, n), spill_cap=sc_split,
-        kernel=cfg.deliver_kernel_resolved,
-        occupancy=cfg.phase1_kernel_resolved)
+        kernel=cfg.deliver_kernel_resolved)
 
     # bk_mbox is not donated for the same reason as b2_fn's mk_mbox (no
     # same-shaped output to alias; liveness frees it after the slot loop).

@@ -44,7 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from gossip_simulator_tpu.config import Config
-from gossip_simulator_tpu.models.overlay import (phase1_slot_fns,
+from gossip_simulator_tpu.models.overlay import (process_breakup_slot,
+                                                 process_makeup_slot,
                                                  spill_enabled)
 from gossip_simulator_tpu.ops.mailbox import deliver_pair
 from gossip_simulator_tpu.ops.select import first_true_indices
@@ -417,10 +418,6 @@ def make_step_fn(cfg: Config, n_local: int | None = None, ids_fn=None,
     # untouched.
     sc = ticks_spill_cap(cfg, n_rows) if sm else 0
     prefix = PREFIX_DRAIN
-    # Phase-1 megakernel gate: the SHARED slot closures, swapped for
-    # their fused forms exactly like overlay.make_round_fn -- both
-    # engines select through the one phase1_slot_fns seam.
-    bk_slot_fn, mk_slot_fn = phase1_slot_fns(cfg)
 
     def _deliver_both(src_pay, dst, typ, evalid, m_live, spill_in):
         # Both message types in ONE sorted pass (ops.mailbox.deliver_pair;
@@ -542,7 +539,7 @@ def make_step_fn(cfg: Config, n_local: int | None = None, ids_fn=None,
             src = jnp.where(has, pay // b, 0)
             toff = jnp.where(has, pay % b, 0)
             kk = jax.random.fold_in(rkey, sl)
-            friends, cnt, nf, rp = bk_slot_fn(
+            friends, cnt, nf, rp = process_breakup_slot(
                 n, fanout, friends, cnt, src, has, ids, kk)
             mk_em_dst = em_set(mk_em_dst, sl, jnp.where(rp, nf, -1))
             mk_em_toff = em_set(mk_em_toff, sl, toff)
@@ -561,7 +558,7 @@ def make_step_fn(cfg: Config, n_local: int | None = None, ids_fn=None,
             src = jnp.where(has, pay // b, 0)
             toff = jnp.where(has, pay % b, 0)
             kk = jax.random.fold_in(ekey, sl)
-            friends, cnt, victim, ev = mk_slot_fn(
+            friends, cnt, victim, ev = process_makeup_slot(
                 fanin, friends, cnt, src, has, kk)
             bk_em_dst = em_set(bk_em_dst, sl, jnp.where(ev, victim, -1))
             bk_em_toff = em_set(bk_em_toff, sl, toff)

@@ -413,7 +413,6 @@ def make_window_step_fn(cfg: Config, n_local: int | None = None):
     eps = float(cfg.pushsum_eps)
     tgt = eps_target(cfg)
     dkern = cfg.deliver_kernel_resolved
-    p2 = cfg.phase2_kernel_resolved
 
     def step_fn(st: PushSumState, base_key: jax.Array) -> PushSumState:
         n, k = st.friends.shape
@@ -425,26 +424,17 @@ def make_window_step_fn(cfg: Config, n_local: int | None = None):
         # tick offset (ent % b) orders SI deliveries within the window;
         # sums commute, so only the destination row matters here.
         m = st.mail_cnt[0, slot]
-        if p2 == "pallas":
-            # Phase-2 megakernel: the whole slot decodes and
-            # scatter-adds in ONE pass -- no dynamic-slice chunk
-            # round-trips (integer adds commute, so this is
-            # bit-identical to any chunking).
-            from gossip_simulator_tpu.ops import pallas_megakernel as mk
-            mass = mk.fused_drain_sum(st.mass, st.mail_ids, st.mail_mass,
-                                      slot, m, cap=cap, b=b)
-        else:
-            chunks = (m + ccap - 1) // ccap
+        chunks = (m + ccap - 1) // ccap
 
-            def body(j, acc):
-                off0 = slot * cap + j * ccap
-                ent = jax.lax.dynamic_slice(st.mail_ids, (off0,), (ccap,))
-                rows = jax.lax.dynamic_slice(
-                    st.mail_mass, (off0, 0), (ccap, C))
-                ok = j * ccap + jnp.arange(ccap, dtype=I32) < m
-                return deposit_sum(acc, ent // b, rows, ok, kernel=dkern)
+        def body(j, acc):
+            off0 = slot * cap + j * ccap
+            ent = jax.lax.dynamic_slice(st.mail_ids, (off0,), (ccap,))
+            rows = jax.lax.dynamic_slice(
+                st.mail_mass, (off0, 0), (ccap, C))
+            ok = j * ccap + jnp.arange(ccap, dtype=I32) < m
+            return deposit_sum(acc, ent // b, rows, ok, kernel=dkern)
 
-            mass = jax.lax.fori_loop(0, chunks, body, st.mass)
+        mass = jax.lax.fori_loop(0, chunks, body, st.mass)
         m3 = _normalize(mass.reshape(n, dim + 1, LIMBS))
         crashed = (flags & event.CRASHED) > 0
         rel, rep = metric_rel(cfg, m3, crashed)

@@ -617,8 +617,7 @@ def _deliver_columns_impl(mats, n, cap, chunk, flat, carry, spill_in=None,
 
 def make_hosted_column_delivery(n: int, cap: int, chunk,
                                 per_call_chunks: int = 256,
-                                spill_cap: int = 0, kernel: str = "xla",
-                                occupancy: str = "xla"):
+                                spill_cap: int = 0, kernel: str = "xla"):
     """deliver_columns(flat=True) as a HOST-driven sequence of bounded
     device calls -- the memory-scale overlay's delivery (overlay.
     make_split_round_fn).  One fused delivery of a full emission row is
@@ -653,15 +652,7 @@ def make_hosted_column_delivery(n: int, cap: int, chunk,
     round's overflow pairs, every chunk collects overflow into a
     (2, spill_cap + 1) accumulator instead of dropping (see
     _compact_chunk_step), and the return gains the final pairs array --
-    the memory-scale overlay's lossless-membership path.
-
-    `occupancy="pallas"` (the -phase1-kernel gate) replaces the
-    per-row jitted popcount round-trips with ONE fused pass + transfer
-    per emission matrix (ops.pallas_overlay_kernel.fused_hosted_chunk)
-    when the caller has no write-time totals -- the first round after a
-    checkpoint restore, and every round with -overlay-dead-skip off.
-    Integer block sums, so the ladder re-selects exactly the same widths
-    (and callers passing `row_totals` are untouched either way)."""
+    the memory-scale overlay's lossless-membership path."""
     widths = tuple(sorted({int(w) for w in
                            (chunk if isinstance(chunk, (tuple, list))
                             else (chunk,))}))
@@ -782,11 +773,6 @@ def make_hosted_column_delivery(n: int, cap: int, chunk,
                 mbox, count, dropped, pairs, scnt = kspill_in(
                     mbox, count, dropped, pairs, scnt, spill_in)
                 jax.block_until_ready(mbox)
-        if row_totals is None and occupancy == "pallas":
-            from gossip_simulator_tpu.ops.pallas_overlay_kernel import \
-                fused_hosted_chunk
-            occs = jax.device_get([fused_hosted_chunk(mat) for mat in mats])
-            row_totals = [int(v) for occ in occs for v in occ]
         ri = 0
         for mat in mats:
             for c in range(mat.shape[0]):

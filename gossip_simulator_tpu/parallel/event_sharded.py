@@ -153,8 +153,7 @@ def _ring_append(cfg: Config, n_local: int, mail, cnt, dropped, payload,
 
 def _route_and_append(cfg: Config, n_shards: int, n_local: int, mail, cnt,
                       dropped, xovf, dst_global, wslot, off, valid, rcap,
-                      flags=None, words=None, mail_words=None,
-                      phase2: str = "xla"):
+                      flags=None, words=None, mail_words=None):
     """Route (global dst, window slot, tick offset) messages to their owner
     shards and append into the local mail ring.
 
@@ -246,34 +245,6 @@ def _route_and_append(cfg: Config, n_shards: int, n_local: int, mail, cnt,
         out = exchange.route_one(wire, dest, valid, n_shards, rcap,
                                  traffic=exch)
         (recv, ovf), exch = out[:2], out[2] if exch is not None else None
-    if phase2 == "pallas":
-        # Phase-2 megakernel receive side: wire decode, receiving-side
-        # duplicate filter and the ring append as ONE pass over the
-        # routed arrivals (ops/pallas_megakernel.fused_recv_land --
-        # bit-identical to the chain below, incl. the trash cell and
-        # ok-only count increments).  At S > 1 this is the megakernel's
-        # landing point: the all_to_all itself must stay (drain crash
-        # draws are ring-POSITION-keyed, so recv interleaving order is
-        # part of the trajectory -- see the megakernel module
-        # docstring).
-        from gossip_simulator_tpu.ops import pallas_megakernel as mk
-        dwr = event.ring_windows(cfg)
-        capr = (mail.shape[0] - event.ring_tail(cfg, n_local)) // dwr
-        if words is not None:
-            rwords = jnp.stack(
-                [jax.lax.bitcast_convert_type(c, jnp.uint32)
-                 for c in recvs[1:]], axis=1)
-            mail, cnt, dropped, rsup, mail_words = mk.fused_recv_land(
-                mail, cnt, dropped, recv, dw=dwr, cap=capr, b=b,
-                words=rwords, mail_words=mail_words, flags=flags,
-                received_bit=int(event.RECEIVED))
-            return (mail, cnt, dropped, exchange.ovf_join(xo + ovf, exch),
-                    sup_adds + rsup, mail_words)
-        mail, cnt, dropped, rsup = mk.fused_recv_land(
-            mail, cnt, dropped, recv, dw=dwr, cap=capr, b=b, flags=flags,
-            received_bit=int(event.RECEIVED))
-        return (mail, cnt, dropped, exchange.ovf_join(xo + ovf, exch),
-                sup_adds + rsup)
     rvalid = recv >= 0
     r = jnp.maximum(recv, 0)
     rdstl = r // (dw * b)
@@ -463,10 +434,6 @@ def make_sharded_event_step(cfg: Config, mesh):
     # note), so they keep the zero-loss bound; S = 1 is returned
     # unchanged (and DIRECT_SELF_APPEND skips the wire there anyway).
     uniform_dest = cfg.graph in ("kout", "erdos")
-    # Phase-2 megakernel gate, resolved at BUILD time.  The
-    # pipelined exchange path keeps its PR-6 kernels (route/flush split);
-    # the megakernel landing engages on the serial schedule only.
-    p2 = cfg.phase2_kernel_resolved
 
     def wire_cap(m_edges: int) -> int:
         return exchange.chernoff_cap(m_edges, s) if uniform_dest else m_edges
@@ -593,13 +560,12 @@ def make_sharded_event_step(cfg: Config, mesh):
                         event.append_messages(
                             cfg, mail, cnt, dropped, sids, svalid, sticks,
                             st.friends, st.friend_cnt, skey, gid0=gid0,
-                            swords=sw, mail_words=mwords, phase2=p2)
+                            swords=sw, mail_words=mwords)
                     return flags, mail, cnt, dropped, xovf, sa, blk, mwords
                 mail, cnt, dropped, sa, blk = event.append_messages(
                     cfg, mail, cnt, dropped, sids, svalid, sticks,
                     st.friends, st.friend_cnt, skey,
-                    flags=flags if suppress else None, gid0=gid0,
-                    phase2=p2)
+                    flags=flags if suppress else None, gid0=gid0)
                 return flags, mail, cnt, dropped, xovf, sa, blk
             rows = jnp.where(svalid, sids, n_local)
             sidx = jnp.where(svalid, sids, 0)
@@ -671,7 +637,7 @@ def make_sharded_event_step(cfg: Config, mesh):
                     jnp.broadcast_to(off2[:, None],
                                      (width, kwidth)).reshape(-1),
                     edge.reshape(-1), ecap, words=ewords,
-                    mail_words=mwords, phase2=p2)
+                    mail_words=mwords)
                 return (flags, mail, cnt, dropped, xovf, nsup, blk,
                         mwords)
             if pstage is not None:
@@ -696,8 +662,7 @@ def make_sharded_event_step(cfg: Config, mesh):
                                  (width, kwidth)).reshape(-1),
                 jnp.broadcast_to(off2[:, None],
                                  (width, kwidth)).reshape(-1),
-                edge.reshape(-1), ecap, flags=flags if suppress else None,
-                phase2=p2)
+                edge.reshape(-1), ecap, flags=flags if suppress else None)
             if sir:
                 mail, cnt, dropped = _append_local_triggers(
                     cfg, n_local, mail, cnt, dropped, rows, svalid & ~rem,

@@ -102,7 +102,7 @@ def _mass_append(cfg: Config, n_local: int, mail, mailm, cnt, dropped,
 
 def _route_append_mass(cfg: Config, s: int, n_local: int, mail, mailm,
                        cnt, dropped, xovf, dst_global, wslot, off, valid,
-                       rcap, share, phase2: str = "xla"):
+                       rcap, share):
     """Route mass shares to their owner shards and append.  The 1-device
     mesh appends directly (the route is the identity there -- same
     DIRECT_SELF_APPEND argument as the event engine, and what makes the
@@ -123,17 +123,6 @@ def _route_append_mass(cfg: Config, s: int, n_local: int, mail, mailm,
                                traffic=exch)
     (recvs, ovf), exch = out[:2], (out[2] if exch is not None else None)
     recv = recvs[0]
-    if phase2 == "pallas":
-        # Phase-2 megakernel receive side: decode + ring append of the
-        # routed mass rows as one pass (garbage -1-fill columns in empty
-        # wire slots are never written -- same gate as the stray-add
-        # guard below).
-        from gossip_simulator_tpu.ops import pallas_megakernel as mk
-        cap = (mail.shape[0] - pushsum.ring_tail(cfg, n_local)) // dw
-        mail, cnt, dropped, _, mailm = mk.fused_recv_land(
-            mail, cnt, dropped, recv, dw=dw, cap=cap, b=b,
-            words=jnp.stack(recvs[1:], axis=1), mail_words=mailm)
-        return mail, mailm, cnt, dropped, exchange.ovf_join(xo + ovf, exch)
     rvalid = recv >= 0
     r = jnp.maximum(recv, 0)
     rdstl = r // (dw * b)
@@ -163,7 +152,6 @@ def make_sharded_pushsum_step(cfg: Config, mesh):
     eps = float(cfg.pushsum_eps)
     tgt = pushsum.eps_target(cfg)
     dkern = cfg.deliver_kernel_resolved
-    p2 = cfg.phase2_kernel_resolved
     scen = cfg.scenario_resolved
     k = cfg.graph_width
     if n_local * dw * b >= 2 ** 31:
@@ -185,29 +173,19 @@ def make_sharded_pushsum_step(cfg: Config, mesh):
             cfg, st.flags, st.down_since, st.tick, gids, base_key, b)
         slot = (st.tick // b) % dw
         m = st.mail_cnt[0, slot]
-        if p2 == "pallas":
-            # Phase-2 megakernel: whole-slot fused drain.  The static
-            # full-cap scan subsumes the pmax-agreed chunk count (every
-            # shard runs the same trip count by construction; masked
-            # lanes add zero, and integer adds commute).
-            from gossip_simulator_tpu.ops import pallas_megakernel as mk
-            mass = mk.fused_drain_sum(st.mass, st.mail_ids, st.mail_mass,
-                                      slot, m, cap=cap, b=b)
-        else:
-            # pmax-agreed chunk count: every shard runs the same loop
-            # trip count (shards with fewer entries deposit masked
-            # no-ops).
-            chunks = (jax.lax.pmax(m, AXIS) + ccap - 1) // ccap
+        # pmax-agreed chunk count: every shard runs the same loop trip
+        # count (shards with fewer entries deposit masked no-ops).
+        chunks = (jax.lax.pmax(m, AXIS) + ccap - 1) // ccap
 
-            def body(j, acc):
-                off0 = slot * cap + j * ccap
-                ent = jax.lax.dynamic_slice(st.mail_ids, (off0,), (ccap,))
-                rows = jax.lax.dynamic_slice(
-                    st.mail_mass, (off0, 0), (ccap, C))
-                ok = j * ccap + jnp.arange(ccap, dtype=I32) < m
-                return deposit_sum(acc, ent // b, rows, ok, kernel=dkern)
+        def body(j, acc):
+            off0 = slot * cap + j * ccap
+            ent = jax.lax.dynamic_slice(st.mail_ids, (off0,), (ccap,))
+            rows = jax.lax.dynamic_slice(
+                st.mail_mass, (off0, 0), (ccap, C))
+            ok = j * ccap + jnp.arange(ccap, dtype=I32) < m
+            return deposit_sum(acc, ent // b, rows, ok, kernel=dkern)
 
-            mass = jax.lax.fori_loop(0, chunks, body, st.mass)
+        mass = jax.lax.fori_loop(0, chunks, body, st.mass)
         m3 = pushsum._normalize(mass.reshape(n_local, dim + 1, LIMBS))
         crashed = (flags & event.CRASHED) > 0
         rel, rep = pushsum.metric_rel(cfg, m3, crashed)
@@ -232,7 +210,7 @@ def make_sharded_pushsum_step(cfg: Config, mesh):
         mail, mailm, cnt, ddrop, dxovf = _route_append_mass(
             cfg, s, n_local, st.mail_ids, st.mail_mass, st.mail_cnt,
             ddrop, xv0, dst, wslot, off, lane_valid, rcap,
-            share, phase2=p2)
+            share)
         dxovf, exch_new = exchange.ovf_split(dxovf)
         cnt = cnt.at[0, slot].set(0)
         dm = lane_valid.sum(dtype=I32)

@@ -228,32 +228,6 @@ _register("pallas_graph.block_rows", "gossip_simulator_tpu.ops.pallas_graph",
           "seeds per block (row0 // block + blk), so a different block "
           "height generates a different graph -- the gate always rejects "
           "alternatives")
-_register("pallas_megakernel.drain_block",
-          "gossip_simulator_tpu.ops.pallas_megakernel",
-          8, (4, 8, 16, 32), int, "never",
-          "PALLAS_VALIDATION.json",
-          "phase-2 megakernel pushsum-drain serial unroll (lanes per fori "
-          "iteration); awaiting real TPU evidence -- interpret-mode "
-          "timings would persist noise, so never table-persisted")
-_register("pallas_megakernel.recv_block",
-          "gossip_simulator_tpu.ops.pallas_megakernel",
-          8, (4, 8, 16, 32), int, "never",
-          "PALLAS_VALIDATION.json",
-          "phase-2 megakernel receive-landing serial unroll (routed lanes "
-          "per fori iteration); same TPU-evidence gate as drain_block")
-_register("pallas_overlay.slot_block",
-          "gossip_simulator_tpu.ops.pallas_overlay_kernel",
-          512, (128, 256, 512, 1024), int, "never",
-          "PALLAS_VALIDATION.json",
-          "phase-1 overlay megakernel negotiate/request rows per serial "
-          "block; awaiting real TPU evidence -- interpret-mode timings "
-          "would persist noise, so never table-persisted")
-_register("pallas_overlay.chunk_block",
-          "gossip_simulator_tpu.ops.pallas_overlay_kernel",
-          1024, (256, 512, 1024, 2048), int, "never",
-          "PALLAS_VALIDATION.json",
-          "phase-1 hosted-occupancy columns per serial block (the ladder "
-          "re-selection pass); same TPU-evidence gate as slot_block")
 _register("config.overlay_ticks_auto_max", "gossip_simulator_tpu.config",
           10_000_000, (1_000_000, 10_000_000), int, "never",
           "unrecorded: pre-PR-21 TPU sweep, re-measure",
@@ -311,11 +285,7 @@ SPACES: dict[str, Space] = {
             "never table-persisted)"),
     "block_shapes": Space(
         name="block_shapes",
-        tunables=("pallas_graph.block_rows",
-                  "pallas_megakernel.drain_block",
-                  "pallas_megakernel.recv_block",
-                  "pallas_overlay.slot_block",
-                  "pallas_overlay.chunk_block"),
+        tunables=("pallas_graph.block_rows",),
         workload=dict(fanout=6, graph="kout", backend="jax", crashrate=0.0,
                       coverage_target=0.95, max_rounds=3000, pallas=True),
         doc="Pallas graph-generator block height (TPU only: the gate "

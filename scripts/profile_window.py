@@ -72,9 +72,9 @@ def parse_trace(trace_dir: str,
 # Roofline (--roofline): bytes-touched-per-delivered-message from the SoA
 # column layout, combined with the measured CPU floors already committed in
 # PROFILE_OVERLAY.json / PROFILE_EXCHANGE.json, written to ROOFLINE.json.
-# The table is the commitment the phase-2 megakernel is judged against:
-# each term lists the minimum memory traffic its fused pass can touch, so a
-# measured ns/message divides into a stated factor-off-roofline.
+# Each term lists the minimum memory traffic a delivered message must
+# touch, so a measured ns/message divides into a stated factor-off-roofline
+# (benchmark/roofline.py copies the emit/deliver/combine derivations).
 # ---------------------------------------------------------------------------
 
 # HBM bandwidth the ns/message floors are quoted at.  TPU v4 HBM2 is
@@ -129,162 +129,13 @@ def _roofline_terms(fanout: int, rumors: int, pushsum_dim: int) -> dict:
     return terms, total, w, c
 
 
-def _phase1_terms(k: int, cap: int) -> dict:
-    """Analytic bytes per NODE per processed mailbox SLOT for the phase-1
-    overlay pipeline (the -phase1-kernel commitment): int32 friends[n, k]
-    + friend_cnt[n] state, int32 slot columns.  The fused column lists
-    the single-traversal minimum (ops/pallas_overlay_kernel); the xla
-    column counts the one-hot op chain's full-array passes
-    (overlay.process_*_slot: ~10 separate (n, k)-wide reads for the
-    match scan, column gets/sets and blend masks), so the quotient IS
-    the stated traffic gap the kernel closes."""
-    xla_nk_passes = 10   # in_range+match scan, 2x _col_get, 3x _col_set
-    #                      (each an (n,k) read + blend write), posval/
-    #                      reply blends -- counted from the op chain
-    fused_nk_passes = 2  # one read + one write of friends per block
-    terms = {
-        "slot_scan": {
-            "bytes_per_node_slot": 4,
-            "derivation": "mailbox slot column read (int32 4); the has "
-                          "mask and src clamp stay in-register in both "
-                          "forms",
-        },
-        "negotiate": {
-            "bytes_per_node_slot": 4 * k * fused_nk_passes + 8 + 4,
-            "xla_bytes_per_node_slot": 4 * k * xla_nk_passes + 8 + 4,
-            "derivation": f"friends row traversal (int32 4*k={4 * k} per "
-                          f"pass; fused {fused_nk_passes} passes vs xla "
-                          f"~{xla_nk_passes}) + cnt read+write (8) + "
-                          "XLA-side draw read (4)",
-        },
-        "reply": {
-            "bytes_per_node_slot": 4,
-            "derivation": "emission column write (int32 4), already "
-                          "where(mask, dst, -1)-encoded in-register; the "
-                          "write-time count is a register reduction",
-        },
-        "hosted_delivery": {
-            "bytes_per_node_slot": 4,
-            "derivation": "occupancy pre-pass over the emission rows "
-                          "(int32 4/entry, one fused pass + ONE transfer "
-                          f"for all {cap} rows vs a jitted popcount "
-                          "round-trip per row on the host ladder)",
-        },
-    }
-    total = sum(t["bytes_per_node_slot"] for t in terms.values())
-    xla_total = sum(t.get("xla_bytes_per_node_slot",
-                          t["bytes_per_node_slot"])
-                    for t in terms.values())
-    return terms, total, xla_total
-
-
-def _measure_interpret_overlay() -> dict:
-    """CPU-measured interpret-mode rows for the fused phase-1 passes --
-    the parity-surface cost stated next to the analytic floor, same
-    rationale as _measure_interpret_megakernel."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from gossip_simulator_tpu.ops import pallas_overlay_kernel as pok
-
-    rng = np.random.default_rng(0)
-    n, k, fanout = 4096, 6, 3
-    cnt = jnp.asarray(rng.integers(0, k + 1, n), jnp.int32)
-    fr = jnp.where(jnp.arange(k, dtype=jnp.int32)[None, :] < cnt[:, None],
-                   jnp.asarray(rng.integers(0, n, (n, k)), jnp.int32), -1)
-    src = jnp.asarray(rng.integers(0, n, n), jnp.int32)
-    has = jnp.asarray(rng.random(n) < 0.5)
-    draw = jnp.asarray(rng.integers(0, n, n), jnp.int32)
-    t0 = time.perf_counter()
-    out = pok.fused_negotiate(fr, cnt, src, has, draw, kind="breakup",
-                              limit=fanout, interpret=True)
-    jax.block_until_ready(out[0])
-    neg_s = time.perf_counter() - t0
-    w = jnp.asarray(rng.integers(0, n, n), jnp.int32)
-    t0 = time.perf_counter()
-    out = pok.fused_request_round(fr, cnt, w, fanout=fanout,
-                                  interpret=True)
-    jax.block_until_ready(out[0])
-    req_s = time.perf_counter() - t0
-    mat = jnp.where(jnp.asarray(rng.random((8, n)) < 0.3),
-                    jnp.asarray(rng.integers(0, n, (8, n)), jnp.int32), -1)
-    t0 = time.perf_counter()
-    occ = pok.fused_hosted_chunk(mat, interpret=True)
-    jax.block_until_ready(occ)
-    occ_s = time.perf_counter() - t0
-    return {
-        "mode": "interpret (single trace+run, CPU correctness surface)",
-        "rows": n,
-        "negotiate_s": neg_s,
-        "negotiate_ns_per_row": neg_s / n * 1e9,
-        "request_s": req_s,
-        "request_ns_per_row": req_s / n * 1e9,
-        "occupancy_lanes": 8 * n,
-        "occupancy_s": occ_s,
-        "occupancy_ns_per_lane": occ_s / (8 * n) * 1e9,
-    }
-
-
-def _measure_interpret_megakernel() -> dict:
-    """CPU-scale measured rows for the fused passes in interpret mode.
-    Interpret mode is the correctness surface, not a fast path -- these
-    rows exist so ROOFLINE.json states the measured parity cost next to
-    the analytic floor instead of implying interpret speed matters."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from gossip_simulator_tpu.ops import pallas_megakernel as mk
-
-    rng = np.random.default_rng(0)
-    m, k, dw, cap, b = 2048, 6, 2, 8192, 8
-    n = 4096
-    sf = jnp.asarray(rng.integers(0, n, (m, k)), jnp.int32)
-    drop = jnp.asarray(rng.random((m, k)) < 0.1)
-    sv = jnp.asarray(rng.random(m) < 0.9)
-    ws = jnp.asarray(rng.integers(0, dw, m), jnp.int32)
-    off = jnp.asarray(rng.integers(0, b, m), jnp.int32)
-    ring = jnp.zeros((dw * cap + m * k,), jnp.int32)
-    cnt = jnp.zeros((1, dw), jnp.int32)
-    t0 = time.perf_counter()
-    out = mk.fused_emit(ring, cnt, sf, drop, sv, ws, off, dw=dw, cap=cap,
-                        b=b, interpret=True)
-    jax.block_until_ready(out[0])
-    emit_s = time.perf_counter() - t0
-    lanes = m * k
-
-    ids = jnp.asarray(rng.integers(0, n * b, dw * cap), jnp.int32)
-    mass = jnp.asarray(rng.integers(-9, 9, (dw * cap, 8)), jnp.int32)
-    acc = jnp.zeros((n, 8), jnp.int32)
-    t0 = time.perf_counter()
-    acc = mk.fused_drain_sum(acc, ids, mass, jnp.asarray(0, jnp.int32),
-                             jnp.asarray(cap, jnp.int32), cap=cap, b=b,
-                             interpret=True)
-    jax.block_until_ready(acc)
-    drain_s = time.perf_counter() - t0
-    return {
-        "mode": "interpret (single trace+run, CPU correctness surface)",
-        "emit_lanes": lanes,
-        "emit_s": emit_s,
-        "emit_ns_per_lane": emit_s / lanes * 1e9,
-        "drain_lanes": cap,
-        "drain_s": drain_s,
-        "drain_ns_per_lane": drain_s / cap * 1e9,
-    }
-
-
 def write_roofline(out_path: str, fanout: int, rumors: int,
-                   pushsum_dim: int, date: str, max_degree: int = 6,
-                   mailbox_cap: int = 16) -> int:
+                   pushsum_dim: int, date: str) -> int:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     terms, total, w, c = _roofline_terms(fanout, rumors, pushsum_dim)
     for t in terms.values():
         t["ns_per_message_at_tpu_v4_hbm"] = (
             t["bytes_per_message"] / TPU_V4_HBM_GBPS)
-    p1_terms, p1_total, p1_xla_total = _phase1_terms(max_degree,
-                                                     mailbox_cap)
-    for t in p1_terms.values():
-        t["ns_per_node_slot_at_tpu_v4_hbm"] = (
-            t["bytes_per_node_slot"] / TPU_V4_HBM_GBPS)
     evidence = []
     po = os.path.join(repo, "PROFILE_OVERLAY.json")
     if os.path.exists(po):
@@ -309,27 +160,10 @@ def write_roofline(out_path: str, fanout: int, rumors: int,
             "note": "measured CPU rank route (XLA, per wire lane) -- "
                     "the route term's CPU reality check",
         })
-    meas = _measure_interpret_megakernel()
-    evidence.append({
-        "source": "measured this session",
-        "row": "pallas_megakernel interpret",
-        "emit_ns_per_lane": round(meas["emit_ns_per_lane"], 1),
-        "drain_ns_per_lane": round(meas["drain_ns_per_lane"], 1),
-        "note": meas["mode"],
-    })
-    p1_meas = _measure_interpret_overlay()
-    evidence.append({
-        "source": "measured this session",
-        "row": "pallas_overlay_kernel interpret",
-        "negotiate_ns_per_row": round(p1_meas["negotiate_ns_per_row"], 1),
-        "request_ns_per_row": round(p1_meas["request_ns_per_row"], 1),
-        "occupancy_ns_per_lane": round(p1_meas["occupancy_ns_per_lane"], 1),
-        "note": p1_meas["mode"],
-    })
     doc = {
         "session": "r19",
         "date": date,
-        "device": "cpu (TPU rows queued -- see tpu_status)",
+        "device": "cpu",
         "hbm_bw_GBps": {"tpu_v4": TPU_V4_HBM_GBPS,
                         "source": "public chip spec; CPU floors are "
                                   "measured, not quoted"},
@@ -348,25 +182,10 @@ def write_roofline(out_path: str, fanout: int, rumors: int,
         "total_bytes_per_message": round(total, 2),
         "total_ns_per_message_at_tpu_v4_hbm": round(
             total / TPU_V4_HBM_GBPS, 4),
-        "phase1_shape": {"max_degree": max_degree,
-                         "mailbox_cap": mailbox_cap},
-        "phase1_terms": p1_terms,
-        "phase1_total_bytes_per_node_slot": round(p1_total, 2),
-        "phase1_xla_bytes_per_node_slot": round(p1_xla_total, 2),
-        "phase1_traffic_gap": round(p1_xla_total / p1_total, 2),
-        "phase1_total_ns_per_node_slot_at_tpu_v4_hbm": round(
-            p1_total / TPU_V4_HBM_GBPS, 4),
         "evidence": evidence,
         "tpu_status": {
-            "status": "queued",
-            "queued_since": "r18",
+            "status": "not measured on a chip",
             "date": date,
-            "note": "not measured on a chip yet; the "
-                    "megakernel_50m_twins bench row will report measured "
-                    "ns/message against total_ns_per_message_at_tpu_v4_"
-                    "hbm, and the phase1_kernel_100m_twins row measured "
-                    "overlay ns/round against phase1_total_ns_per_node_"
-                    "slot_at_tpu_v4_hbm",
         },
     }
     with open(out_path, "w") as f:
@@ -377,11 +196,6 @@ def write_roofline(out_path: str, fanout: int, rumors: int,
           f"B/message -> {ps_msg:.3f} ps/message at TPU v4 HBM")
     for nm, t in terms.items():
         print(f"  {nm:8s} {t['bytes_per_message']:7.2f} B/msg")
-    print(f"phase-1: {doc['phase1_total_bytes_per_node_slot']} B/node-slot "
-          f"fused vs {doc['phase1_xla_bytes_per_node_slot']} xla "
-          f"({doc['phase1_traffic_gap']}x traffic gap)")
-    for nm, t in p1_terms.items():
-        print(f"  {nm:16s} {t['bytes_per_node_slot']:7.2f} B/node-slot")
     return 0
 
 
@@ -410,18 +224,12 @@ def main() -> int:
     ap.add_argument("--rumors", type=int, default=16,
                     help="roofline R (words = ceil(R/32))")
     ap.add_argument("--pushsum-dim", type=int, default=1)
-    ap.add_argument("--max-degree", type=int, default=6,
-                    help="phase-1 roofline k (friends columns)")
-    ap.add_argument("--mailbox-cap", type=int, default=16,
-                    help="phase-1 roofline emission rows (occupancy term)")
     ap.add_argument("--date", default="2026-08-07",
-                    help="stamp for the roofline / queued TPU rows")
+                    help="stamp for the roofline")
     args = ap.parse_args()
     if args.roofline:
         return write_roofline(args.roofline_out, args.fanout, args.rumors,
-                              args.pushsum_dim, args.date,
-                              max_degree=args.max_degree,
-                              mailbox_cap=args.mailbox_cap)
+                              args.pushsum_dim, args.date)
     on_tpu = jax.default_backend() == "tpu"
     if args.phase == "overlay":
         cfg = Config(n=args.n, graph="overlay",

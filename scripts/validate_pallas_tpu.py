@@ -22,22 +22,8 @@ Checks (all on freshly generated tables):
   chunk step both layouts, spill counts + pair multiset, ring append,
   deliver/deliver_pair gates, deposits, unique-set dual ring.  These are
   PRNG-free, so they also run in interpret mode on CPU hosts
-  (--interpret), where the dated verdict is MERGED into the existing
+  (--interpret), where the verdict is MERGED into the existing
   artifact without disturbing recorded TPU results.
-* megakernel (ISSUE 18, run_megakernel_checks): the -phase2-kernel fused
-  passes (ops/pallas_megakernel) bit-identical to their XLA chains --
-  the emission reservation chain, the sharded receive landing, the pushsum drain
-  (including chunk-split commutation), and the joint multi-rumor
-  deposit.  PRNG-free like the deliver checks; --interpret merges
-  megakernel_interpret, a TPU pass merges megakernel_tpu.
-* overlay kernel (ISSUE 19, run_overlay_kernel_checks): the
-  -phase1-kernel fused passes (ops/pallas_overlay_kernel) bit-identical
-  to the overlay slot chains -- fused_negotiate vs
-  process_makeup_slot/process_breakup_slot on a dense random state,
-  fused_request_round vs the bootstrap
-  append block, fused_hosted_chunk vs the per-row popcount.  RNG draws
-  stay XLA-side by design; --interpret merges a DATED overlay_interpret
-  verdict, a TPU pass merges overlay_tpu (queued in BENCH.md).
 
 Run: python scripts/validate_pallas_tpu.py [--out PALLAS_VALIDATION.json]
      python scripts/validate_pallas_tpu.py --interpret   # CPU deliver-only
@@ -274,179 +260,6 @@ def run_deliver_checks() -> dict:
     }
 
 
-def run_megakernel_checks() -> dict:
-    """Bit-identity of the phase-2 fused passes against the XLA chains
-    they replace (ops/pallas_megakernel vs ops/mailbox + models/epidemic
-    + the append_messages reservation chain).  PRNG-free: RNG draws stay
-    on the XLA side by design, so the same assertions hold natively on
-    TPU and in interpret mode on CPU."""
-    import jax.numpy as jnp
-
-    from gossip_simulator_tpu.models import epidemic
-    from gossip_simulator_tpu.ops import mailbox as mb
-    from gossip_simulator_tpu.ops import pallas_megakernel as mk
-
-    mode = "tpu" if jax.default_backend() == "tpu" else "interpret"
-    I32 = jnp.int32
-    checks = []
-
-    def add(name, ok, **detail):
-        checks.append({"name": name, "ok": bool(ok), **detail})
-
-
-    rng = np.random.default_rng(18)
-    # drain: random masses, live-prefix mask, chunk-split commutation.
-    n, cols, cap, b = 7, 8, 24, 4
-    ids = jnp.asarray(rng.integers(0, n * b, 2 * cap), I32)
-    mass = jnp.asarray(rng.integers(-9, 9, (2 * cap, cols)), I32)
-    acc0 = jnp.asarray(rng.integers(0, 5, (n, cols)), I32)
-    m = jnp.asarray(17, I32)
-    fa = mk.fused_drain_sum(acc0, ids, mass, jnp.asarray(1, I32), m,
-                            cap=cap, b=b)
-    ok = jnp.arange(cap, dtype=I32) < m
-    xa = mb.deposit_sum(acc0, ids[cap:] // b, mass[cap:], ok)
-    xa2 = mb.deposit_sum(acc0, ids[cap:cap + 9] // b, mass[cap:cap + 9],
-                         ok[:9])
-    xa2 = mb.deposit_sum(xa2, ids[cap + 9:] // b, mass[cap + 9:], ok[9:])
-    add("drain_sum_parity", bool((fa == xa).all()))
-    add("drain_sum_chunk_split_commutes", bool((fa == xa2).all()))
-
-    # receive landing: random wire with empty slots + duplicate filter.
-    dw, rcap, b2, nl, mw = 3, 5, 4, 6, 64
-    wire = rng.integers(0, nl * dw * b2, mw)
-    wire = np.where(rng.random(mw) < 0.75, wire, -1)
-    recv = jnp.asarray(wire, I32)
-    flags = jnp.asarray(rng.integers(0, 2, nl), jnp.uint8)
-    wv = jnp.asarray(rng.integers(1, 99, (mw, 2)), np.uint32)
-    ring0 = jnp.zeros((dw * rcap + 1,), I32)
-    wring0 = jnp.zeros((dw * rcap + 1, 2), jnp.uint32)
-    cnt0 = jnp.asarray(rng.integers(0, 2, (1, dw)), I32)
-    fi, fc, fd, fs, fw = mk.fused_recv_land(
-        ring0, cnt0, jnp.zeros((), I32), recv, dw=dw, cap=rcap, b=b2,
-        words=wv, mail_words=wring0, flags=flags)
-    rv = recv >= 0
-    r = jnp.maximum(recv, 0)
-    rd, rw_, ro = r // (dw * b2), (r // b2) % dw, r % b2
-    dup = rv & ((flags.at[rd].get() & jnp.uint8(1)) > 0)
-    xs = ((rw_[:, None] == jnp.arange(dw, dtype=I32)[None, :])
-          & dup[:, None]).sum(axis=0, dtype=I32)
-    rv = rv & ~dup
-    wvx = jnp.where(rv[:, None], wv, jnp.uint32(0))
-    (xi, xw), xc, xd = mb.ring_append(
-        (ring0, wring0), cnt0, jnp.zeros((), I32),
-        (rd * b2 + ro, wvx), rw_, rv, dw, rcap)
-    add("recv_land_parity",
-        bool((fi == xi).all()) and bool((fw == xw).all())
-        and bool((fc == xc).all()) and int(fd) == int(xd)
-        and bool((fs == xs).all()))
-
-    # joint deposit vs the sequential pair.
-    bs, nn, rr, kk = 3, 9, 4, 3
-    me = nn * kk
-    dst = jnp.asarray(rng.integers(0, nn, me), I32)
-    slots = jnp.asarray(rng.integers(0, bs, me), I32)
-    valid = jnp.asarray(rng.random(me) < 0.7)
-    nb = jnp.asarray(rng.random((nn, rr)) < 0.5)
-    p0 = jnp.asarray(rng.integers(0, 3, (bs, nn)), I32)
-    pr0 = jnp.asarray(rng.integers(0, 3, (bs, nn, rr)), I32)
-    fp, fpr = mk.fused_deposit_both(p0, pr0, dst, slots, valid, nb)
-    xp = epidemic.deposit_local(p0.reshape(-1), dst, slots, valid, n=nn)
-    xpr = epidemic.deposit_rumors(pr0, dst, slots, valid, nb)
-    add("deposit_both_parity",
-        bool((fp.reshape(-1) == xp).all()) and bool((fpr == xpr).all()))
-
-    return {
-        "mode": mode,
-        "device": jax.devices()[0].device_kind,
-        "checks": checks,
-        "all_pass": all(c["ok"] for c in checks),
-    }
-
-
-def run_overlay_kernel_checks(date: str | None = None) -> dict:
-    """Bit-identity of the phase-1 fused passes against the overlay slot
-    chains they replace (ops/pallas_overlay_kernel vs
-    models/overlay.process_*_slot + the bootstrap block + the hosted
-    ladder popcount).  The draws (randint_excluding fresh peer, eviction
-    position, needNewFriend target) are computed XLA-side on the
-    identical keys, so the assertions hold natively on TPU and in
-    interpret mode on CPU."""
-    import jax.numpy as jnp
-
-    from gossip_simulator_tpu.models import overlay as ov
-    from gossip_simulator_tpu.ops import pallas_overlay_kernel as pok
-    from gossip_simulator_tpu.utils import rng as _rng
-
-    mode = "tpu" if jax.default_backend() == "tpu" else "interpret"
-    base = {"mode": mode}
-    if date:
-        base["date"] = date
-    I32 = jnp.int32
-    checks = []
-
-    def add(name, ok, **detail):
-        checks.append({"name": name, "ok": bool(ok), **detail})
-
-
-    # A second, denser state (n a multiple of the block width, every has
-    # lane live) so both the full-block and the overlap-tail schedules
-    # are exercised across the checks.
-    n, k, fanout, fanin = 1024, 6, 3, 3
-    key = jax.random.PRNGKey(19)
-    kc, kf, ks, kk = jax.random.split(key, 4)
-    cnt = jax.random.randint(kc, (n,), 0, k + 1, dtype=I32)
-    fr = jax.random.randint(kf, (n, k), 0, n, dtype=I32)
-    fr = jnp.where(jnp.arange(k, dtype=I32)[None, :] < cnt[:, None],
-                   fr, -1)
-    src = jax.random.randint(ks, (n,), 0, n, dtype=I32)
-    has = jax.random.uniform(jax.random.fold_in(ks, 1), (n,)) < 0.8
-    ids = jnp.arange(n, dtype=I32)
-
-    xf, xc, xnf, xrp = ov.process_breakup_slot(n, fanout, fr, cnt, src,
-                                               has, ids, kk)
-    nf = _rng.randint_excluding(kk, n, (n,), src, ids)
-    ff, fc, rep = pok.fused_negotiate(fr, cnt, src, has, nf,
-                                      kind="breakup", limit=fanout)
-    add("negotiate_breakup_parity",
-        bool((ff == xf).all()) and bool((fc == xc).all())
-        and bool((rep == jnp.where(xrp, xnf, -1)).all()))
-
-    xf, xc, xv, xev = ov.process_makeup_slot(fanin, fr, cnt, src, has, kk)
-    vpos = jax.random.randint(kk, cnt.shape, 0, jnp.maximum(cnt, 1),
-                              dtype=I32)
-    ff, fc, rep = pok.fused_negotiate(fr, cnt, src, has, vpos,
-                                      kind="makeup", limit=fanin)
-    add("negotiate_makeup_parity",
-        bool((ff == xf).all()) and bool((fc == xc).all())
-        and bool((rep == jnp.where(xev, xv, -1)).all()))
-
-    w = jax.random.randint(jax.random.fold_in(kk, 2), (n,), 0, n,
-                           dtype=I32)
-    w = jnp.where(w == ids, (w + 1) % n, w)
-    under = cnt < fanout
-    xf = ov._col_set(fr, jnp.minimum(cnt, k - 1), w, under)
-    ff, fc, fem, fbc = pok.fused_request_round(fr, cnt, w, fanout=fanout)
-    add("request_round_parity",
-        bool((ff == xf).all())
-        and bool((fc == cnt + under.astype(I32)).all())
-        and bool((fem == jnp.where(under, w, -1)).all())
-        and int(fbc) == int(under.sum()))
-
-    mat = jnp.where(jax.random.uniform(kf, (16, 2000)) < 0.3,
-                    jax.random.randint(ks, (16, 2000), 0, n, dtype=I32),
-                    -1)
-    occ = pok.fused_hosted_chunk(mat)
-    add("hosted_occupancy_parity",
-        bool((occ == (mat >= 0).sum(axis=1, dtype=I32)).all()))
-
-    return {
-        **base,
-        "device": jax.devices()[0].device_kind,
-        "checks": checks,
-        "all_pass": all(c["ok"] for c in checks),
-    }
-
-
 def _merge_out(path: str, updates: dict) -> dict:
     """Merge `updates` into the JSON artifact at `path` (preserving any
     recorded sections -- e.g. the CPU --interpret verdict must not erase
@@ -473,21 +286,12 @@ def main() -> int:
                     help="run only the (PRNG-free) delivery-kernel checks "
                          "in interpret mode -- valid on CPU hosts; the "
                          "verdict is merged into --out")
-    ap.add_argument("--date", default="2026-08-07",
-                    help="stamp for the merged interpret/TPU verdicts")
     args = ap.parse_args()
     if args.interpret:
         result = run_deliver_checks()
-        mega = run_megakernel_checks()
-        ovl = run_overlay_kernel_checks(date=args.date)
-        _merge_out(args.out, {"deliver_interpret": result,
-                              "megakernel_interpret": mega,
-                              "overlay_interpret": ovl})
-        print(json.dumps({"deliver_interpret": result,
-                          "megakernel_interpret": mega,
-                          "overlay_interpret": ovl}))
-        return 0 if (result.get("all_pass") and mega.get("all_pass")
-                     and ovl.get("all_pass")) else 1
+        _merge_out(args.out, {"deliver_interpret": result})
+        print(json.dumps({"deliver_interpret": result}))
+        return 0 if result.get("all_pass") else 1
     if jax.default_backend() != "tpu":
         print(json.dumps({"skipped": "no TPU present; interpret-mode PRNG "
                                      "validates nothing (use --interpret "
@@ -495,15 +299,9 @@ def main() -> int:
         return 3
     result = run_checks()
     deliver = run_deliver_checks()
-    mega = run_megakernel_checks()
-    ovl = run_overlay_kernel_checks(date=args.date)
-    _merge_out(args.out, {**result, "deliver_tpu": deliver,
-                          "megakernel_tpu": mega, "overlay_tpu": ovl})
-    print(json.dumps({**result, "deliver_tpu": deliver,
-                      "megakernel_tpu": mega, "overlay_tpu": ovl}))
-    return 0 if (result["all_pass"] and deliver.get("all_pass")
-                 and mega.get("all_pass") and ovl.get("all_pass")) else 1
-
+    _merge_out(args.out, {**result, "deliver_tpu": deliver})
+    print(json.dumps({**result, "deliver_tpu": deliver}))
+    return 0 if (result["all_pass"] and deliver.get("all_pass")) else 1
 
 if __name__ == "__main__":
     sys.exit(main())

@@ -65,15 +65,6 @@ PRE_REGISTRY_DEFAULTS = {
     "event.drain_chunk_hi_lowdeg": 524_288,
     "event.drain_chunk_hi_suppress": 4_194_304,
     "pallas_graph.block_rows": 512,
-    # Phase-2 megakernel (ISSUE 18): serial-lane unroll factors for the
-    # fused drain / receive-landing passes; TPU-only, "never"-persist.
-    "pallas_megakernel.drain_block": 8,
-    "pallas_megakernel.recv_block": 8,
-    # Phase-1 overlay megakernel (ISSUE 19): serial block shapes for the
-    # fused negotiate/request and hosted-occupancy passes; TPU-only,
-    # "never"-persist.
-    "pallas_overlay.slot_block": 512,
-    "pallas_overlay.chunk_block": 1024,
     "config.overlay_ticks_auto_max": 10_000_000,
 }
 

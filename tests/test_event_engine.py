@@ -669,3 +669,143 @@ def test_dup_suppress_sir_ab_identical():
     assert occ_on < occ_off  # data-edge filter engaged (triggers kept)
     np.testing.assert_array_equal(np.asarray(s_on.state.flags),
                                   np.asarray(s_off.state.flags))
+
+
+# --------------------------------------------------------------------------
+# append_messages against a NumPy replica of its reservation chain
+# --------------------------------------------------------------------------
+
+def _emit_reference(cnt0, sf, drop, sv, ws, off, dw, cap, b, ring_len, *,
+                    tb=None, strig=None, sid=None, pmask=None, flags=None,
+                    rbit=1, swords=None):
+    """Sender by sender, in emission order: partition block, duplicate
+    filter, trigger lane (not gated on the sender's validity), an exact
+    contiguous reservation per sender in its arrival slot (all-or-nothing
+    against the slot's capacity), kept edges written in column order and
+    every other lane diverted to its own trash position past the slots."""
+    m, k = sf.shape
+    kw = k + (1 if tb is not None else 0)
+    ids = np.zeros(ring_len, np.int64)
+    words = (None if swords is None
+             else np.zeros((ring_len, swords.shape[1]), np.int64))
+    vcnt = np.zeros(dw, np.int64)
+    adds = np.zeros(dw, np.int64)
+    sup = np.zeros(dw, np.int64)
+    lost = blk = 0
+    for i in range(m):
+        v = bool(sv[i])
+        evs, pays, ec, dc, bn = [], [], 0, 0, 0
+        for kk in range(k):
+            f = int(sf[i, kk])
+            e = v and not drop[i, kk] and f >= 0
+            if pmask is not None and e and pmask[i, kk]:
+                bn += 1
+                e = False
+            if flags is not None and e and (int(flags[max(f, 0)]) & rbit):
+                dc += 1
+                e = False
+            evs.append(e)
+            pays.append(f * b + int(off[i]))
+            ec += e
+        if tb is not None:
+            et = bool(strig[i])
+            evs.append(et)
+            pays.append(tb + int(sid[i]) * b + int(off[i]))
+            ec += et
+        sc = int(ws[i])
+        start = int(cnt0[sc]) + int(vcnt[sc])
+        okr = v and (start + ec <= cap)
+        vcnt[sc] += ec if v else 0
+        adds[sc] += ec if okr else 0
+        sup[sc] += dc
+        lost += 0 if okr else ec
+        blk += bn
+        col = 0
+        for kk in range(kw):
+            e = evs[kk]
+            flat = (sc * cap + start + col if (e and okr)
+                    else dw * cap + i * kw + kk)
+            ids[flat] = pays[kk] if e else 0
+            if words is not None:
+                words[flat] = swords[i] if e else 0
+            col += e
+    return ids, adds, sup, lost, blk, words
+
+
+@pytest.mark.parametrize("variant", ["plain", "part_dup", "trig_words"])
+def test_append_messages_matches_reference(variant):
+    """event.append_messages against the NumPy replica, across the
+    partition / duplicate-filter / trigger / word-row corners with dead
+    senders, -1 friends, dropped edges and slot overflow all present.
+    One delay (delaylow 4, delayhigh 5) so each sender's slot and offset
+    follow from its tick; drops are drawn from the engine's
+    (tick, sender-row)-keyed stream."""
+    import jax
+    import jax.numpy as jnp
+
+    from gossip_simulator_tpu import scenario as _scen
+    from gossip_simulator_tpu.models import epidemic, event
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    I32 = jnp.int32
+    rng = np.random.default_rng(21)
+    n, k, m, cap = 12, 4, 16, 10
+    kw = dict(n=n, graph="kout", fanout=k, delaylow=4, delayhigh=5,
+              droprate=0.2, crashrate=0.0, seed=21, backend="jax")
+    if variant == "part_dup":
+        kw["scenario"] = ('{"groups": 2, "events": [{"type": "partition", '
+                          '"start": 10, "end": 30}]}')
+    cfg = Config(**kw).validate()
+    b, dw = event.batch_ticks(cfg), event.ring_windows(cfg)
+    kwl = k + (1 if variant == "trig_words" else 0)
+    assert event.ring_tail(cfg, n) >= m * kwl  # unique trash lanes
+    ring_len = dw * cap + event.ring_tail(cfg, n)
+
+    friends = rng.integers(-1, n, (n, k))
+    sid = rng.integers(0, n, m)
+    sv = rng.random(m) < 0.85
+    sticks = rng.integers(0, 40, m)
+    cnt0 = rng.integers(0, 2, dw)
+    base_key = _rng.base_key(cfg.seed)
+    rows = jnp.asarray(np.where(sv, sid, n), I32)
+    drop = np.asarray(jax.vmap(
+        lambda kk: jax.random.bernoulli(kk, epidemic.p_eff(cfg, 0.2), (k,)))(
+        event._sender_keys(base_key, _rng.OP_DROP,
+                           jnp.asarray(sticks, I32), rows)))
+    arrive = sticks + 4
+    sf = friends[np.where(sv, sid, 0)]
+    args, refkw = {}, {}
+    if variant == "part_dup":
+        flags = rng.integers(0, 2, n).astype(np.uint8)
+        pmask = np.asarray(_scen.partition_blocked(
+            cfg.scenario_resolved, n, jnp.asarray(sticks, I32)[:, None],
+            rows[:, None], jnp.asarray(sf, I32)))
+        assert pmask.any()
+        args = dict(flags=jnp.asarray(flags))
+        refkw = dict(pmask=pmask, flags=flags)
+    elif variant == "trig_words":
+        strig = rng.random(m) < 0.3
+        swords = rng.integers(1, 99, (m, 2))
+        args = dict(strig=jnp.asarray(strig),
+                    swords=jnp.asarray(swords, jnp.uint32),
+                    mail_words=jnp.zeros((ring_len, 2), jnp.uint32))
+        refkw = dict(tb=event.trigger_base(n, b), strig=strig, sid=sid,
+                     swords=swords)
+    out = event.append_messages(
+        cfg, jnp.zeros((ring_len,), I32), jnp.asarray(cnt0[None, :], I32),
+        jnp.zeros((), I32), jnp.asarray(sid, I32), jnp.asarray(sv),
+        jnp.asarray(sticks, I32), jnp.asarray(friends, I32),
+        jnp.full((n,), k, I32), base_key, **args)
+    xi, xad, xsu, xlo, xbl, xw = _emit_reference(
+        cnt0, sf, drop, sv, (arrive // b) % dw, arrive % b, dw, cap, b,
+        ring_len, **refkw)
+    assert xlo > 0 and drop.any()  # overflow and drops both exercised
+    np.testing.assert_array_equal(np.asarray(out[0]), xi)
+    np.testing.assert_array_equal(np.asarray(out[1])[0] - cnt0, xad)
+    assert int(out[2]) == xlo
+    np.testing.assert_array_equal(np.asarray(out[3]), xsu)
+    assert int(out[4]) == xbl
+    if variant == "part_dup":
+        assert xbl > 0 and xsu.sum() > 0
+    if variant == "trig_words":
+        np.testing.assert_array_equal(np.asarray(out[5]), xw)

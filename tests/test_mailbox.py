@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from gossip_simulator_tpu.ops.mailbox import deliver, segment_ranks
 
@@ -407,3 +408,149 @@ def test_hosted_column_delivery_spill_matches_fused():
                                       np.asarray(want_pairs))
     # The spill actually fired in this shape (collision-heavy rows).
     assert int(want_cnt) > 0
+
+
+# --------------------------------------------------------------------------
+# Receive landing, sum deposit and ring deposits against NumPy loops
+# --------------------------------------------------------------------------
+
+def _ring_append_reference(ring, cnt, vals, wslot, valid, dw, cap):
+    """Entry by entry: the next free position of its slot, or counted as
+    dropped once the slot is full; the trash cell past the slots reads 0
+    once any entry was diverted there."""
+    ring, cnt = ring.copy(), cnt.copy()
+    dropped = 0
+    for i in np.nonzero(valid)[0]:
+        w = wslot[i]
+        if cnt[w] < cap:
+            ring[w * cap + cnt[w]] = vals[i]
+            cnt[w] += 1
+        else:
+            dropped += 1
+    if (~valid).any() or dropped:
+        ring[dw * cap] = 0
+    return ring, cnt, dropped
+
+
+@pytest.mark.parametrize("dual", [False, True], ids=["ids", "ids_words"])
+def test_receive_landing_matches_reference(dual):
+    """The sharded engines' receive side (event_sharded._route_and_append
+    after the route): decode each wire lane (dst * dw * b + slot * b +
+    offset, -1 for an empty lane), drop arrivals whose destination has
+    already received (counted per arrival slot), ring_append the rest.
+    Checked against a NumPy loop on a batch with empty lanes, duplicates
+    and slot overflow."""
+    from gossip_simulator_tpu.ops import mailbox as mb
+
+    I32 = jnp.int32
+    rng = np.random.default_rng(22)
+    dw, cap, b, nl, m, W = 3, 5, 4, 8, 80, 2
+    wire = np.where(rng.random(m) < 0.75, rng.integers(0, nl * dw * b, m),
+                    -1)
+    flags = (rng.random(nl) < 0.35).astype(np.uint8)
+    cnt0 = rng.integers(0, 2, dw)
+    words = rng.integers(1, 99, (m, W))
+    ring0 = np.full(dw * cap + 1, -1)
+    wring0 = np.full((dw * cap + 1, W), 7)
+
+    recv = jnp.asarray(wire, I32)
+    rv = recv >= 0
+    r = jnp.maximum(recv, 0)
+    rd, rw, ro = r // (dw * b), (r // b) % dw, r % b
+    dup = rv & ((jnp.asarray(flags).at[rd].get() & jnp.uint8(1)) > 0)
+    xs = ((rw[:, None] == jnp.arange(dw, dtype=I32)[None, :])
+          & dup[:, None]).sum(axis=0, dtype=I32)
+    rv = rv & ~dup
+    rings = (jnp.asarray(ring0, I32),)
+    pays = (rd * b + ro,)
+    if dual:
+        rings += (jnp.asarray(wring0, jnp.uint32),)
+        pays += (jnp.where(rv[:, None], jnp.asarray(words, jnp.uint32),
+                           jnp.uint32(0)),)
+    xr, xc, xd = mb.ring_append(rings, jnp.asarray(cnt0[None, :], I32),
+                                jnp.zeros((), I32), pays, rw, rv, dw, cap)
+
+    live = wire >= 0
+    w = np.maximum(wire, 0)
+    dst, slot, off = w // (dw * b), (w // b) % dw, w % b
+    isdup = live & ((flags[dst] & 1) > 0)
+    keep = live & ~isdup
+    want_sup = np.bincount(slot[isdup], minlength=dw)
+    want, want_cnt, want_drop = _ring_append_reference(
+        ring0, cnt0, dst * b + off, slot, keep, dw, cap)
+    assert want_drop > 0 and isdup.any() and (~live).any()
+    np.testing.assert_array_equal(np.asarray(xr[0]), want)
+    np.testing.assert_array_equal(np.asarray(xc)[0], want_cnt)
+    assert int(xd) == want_drop
+    np.testing.assert_array_equal(np.asarray(xs), want_sup)
+    if dual:
+        wwant = wring0.copy()
+        wcnt = cnt0.copy()
+        for i in np.nonzero(keep)[0]:
+            if wcnt[slot[i]] < cap:
+                wwant[slot[i] * cap + wcnt[slot[i]]] = words[i]
+                wcnt[slot[i]] += 1
+        wwant[dw * cap] = 0
+        np.testing.assert_array_equal(np.asarray(xr[1]), wwant)
+
+
+def test_deposit_sum_matches_add_at():
+    """mailbox.deposit_sum on the live prefix of one slot, whole and in
+    two chunks (what the chunked pushsum drains do), against np.add.at:
+    integer adds commute, so the chunking never moves the result."""
+    from gossip_simulator_tpu.ops.mailbox import deposit_sum
+
+    I32 = jnp.int32
+    rng = np.random.default_rng(23)
+    n, cols, cap, b, dw = 7, 3, 24, 4, 2
+    ids = rng.integers(0, n * b, dw * cap)
+    mass = rng.integers(-9, 9, (dw * cap, cols))
+    acc0 = rng.integers(0, 5, (n, cols))
+    for slot, m in ((0, 0), (0, 17), (1, cap)):
+        lo = slot * cap
+        want = acc0.copy()
+        np.add.at(want, ids[lo:lo + m] // b, mass[lo:lo + m])
+        ok = jnp.arange(cap, dtype=I32) < m
+        dst = jnp.asarray(ids[lo:lo + cap] // b, I32)
+        rows = jnp.asarray(mass[lo:lo + cap], I32)
+        whole = deposit_sum(jnp.asarray(acc0, I32), dst, rows, ok)
+        np.testing.assert_array_equal(np.asarray(whole), want)
+        c = 5
+        split = deposit_sum(jnp.asarray(acc0, I32), dst[:c], rows[:c],
+                            ok[:c])
+        split = deposit_sum(split, dst[c:], rows[c:], ok[c:])
+        np.testing.assert_array_equal(np.asarray(split), want)
+
+
+def test_ring_deposits_match_add_at():
+    """epidemic.deposit_local (arrival counts into the flat [d * n] ring)
+    and deposit_rumors (each edge adds its sender's new-rumor row into
+    the [d, n, R] ring) against np.add.at, on a multi-rumor batch with
+    invalid edges."""
+    from gossip_simulator_tpu.models import epidemic
+
+    I32 = jnp.int32
+    rng = np.random.default_rng(24)
+    d, n, k, R = 4, 9, 5, 3
+    m = n * k
+    pending = rng.integers(0, 3, (d, n))
+    prum = rng.integers(0, 3, (d, n, R))
+    slots = rng.integers(0, d, m)
+    valid = rng.random(m) < 0.7
+    dst = rng.integers(0, n, m)
+    newbits = rng.random((n, R)) < 0.5
+    got = epidemic.deposit_local(
+        jnp.asarray(pending.reshape(-1), I32), jnp.asarray(dst, I32),
+        jnp.asarray(slots, I32), jnp.asarray(valid), n=n)
+    got_r = epidemic.deposit_rumors(
+        jnp.asarray(prum, I32), jnp.asarray(dst, I32),
+        jnp.asarray(slots, I32), jnp.asarray(valid), jnp.asarray(newbits))
+    want = pending.copy()
+    np.add.at(want, (slots[valid], dst[valid]), 1)
+    want_r = prum.copy()
+    sender = np.arange(m) // k
+    np.add.at(want_r, (slots[valid], dst[valid]),
+              newbits[sender[valid]].astype(np.int64))
+    assert (~valid).any()
+    np.testing.assert_array_equal(np.asarray(got), want.reshape(-1))
+    np.testing.assert_array_equal(np.asarray(got_r), want_r)

@@ -386,3 +386,174 @@ def test_ticks_spill_checkpoint_coercion():
     with pytest.raises(ValueError, match="spill"):
         prepare_overlay_restore_tree(
             live, cfg.replace(backend="sharded"), n_shards=2)
+
+
+# --------------------------------------------------------------------------
+# Slot negotiation, bootstrap request and hosted delivery against
+# per-node loops of the reference's rules
+# --------------------------------------------------------------------------
+
+def _random_state(n, k, seed):
+    """A state with every row class: empty, under-fanin, at-fanout,
+    over-fanout; src hits both present and absent friends, with dead
+    mailbox lanes."""
+    import jax
+
+    I32 = jnp.int32
+    kc, kf, ks, kk = jax.random.split(jax.random.PRNGKey(seed), 4)
+    cnt = jax.random.randint(kc, (n,), 0, k + 1, dtype=I32)
+    fr = jax.random.randint(kf, (n, k), 0, n, dtype=I32)
+    fr = jnp.where(jnp.arange(k, dtype=I32)[None, :] < cnt[:, None],
+                   fr, -1)
+    # Half the live lanes name a present friend, so both the match and
+    # the miss branches run.
+    src = jax.random.randint(ks, (n,), -2, n, dtype=I32)
+    pick = jax.random.randint(jax.random.fold_in(ks, 1), (n,), 0,
+                              jnp.maximum(cnt, 1), dtype=I32)
+    present = jnp.take_along_axis(fr, pick[:, None], axis=1)[:, 0]
+    src = jnp.where((src >= 0) & (src % 2 == 0) & (present >= 0), present,
+                    src)
+    has = src >= 0
+    src = jnp.where(has, src, 0)
+    return fr, cnt, src, has, jnp.arange(n, dtype=I32), kk
+
+
+# n=37 and n=1024: a ragged and a power-of-two node count.
+@pytest.mark.parametrize("n", [37, 1024])
+def test_breakup_slot_matches_reference(n):
+    """process_breakup_slot against simulator.go:76-94 node by node: the
+    first friend equal to the leaver is removed by swap-with-last when
+    the node is over fanout, else replaced in place by a fresh peer (not
+    self, not the leaver) who is sent a makeup."""
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    k, fanout = 5, 3
+    fr, cnt, src, has, ids, kk = _random_state(n, k, seed=7)
+    xf, xc, xnf, xrp = ov.process_breakup_slot(n, fanout, fr, cnt, src,
+                                               has, ids, kk)
+    nf = np.asarray(_rng.randint_excluding(kk, n, (n,), src, ids))
+    f, c, src, has = (np.array(a) for a in (fr, cnt, src, has))
+    reply = np.full(n, -1)
+    for r in range(n):
+        if not has[r]:
+            continue
+        hit = [j for j in range(c[r]) if f[r, j] == src[r]]
+        if not hit:
+            continue
+        pos = hit[0]
+        if c[r] > fanout:
+            f[r, pos] = f[r, c[r] - 1]
+            f[r, c[r] - 1] = -1
+            c[r] -= 1
+        else:
+            assert nf[r] != r and nf[r] != src[r]
+            f[r, pos] = nf[r]
+            reply[r] = nf[r]
+    assert (reply >= 0).any() and (np.asarray(xc) < np.asarray(cnt)).any()
+    np.testing.assert_array_equal(np.asarray(xf), f)
+    np.testing.assert_array_equal(np.asarray(xc), c)
+    np.testing.assert_array_equal(np.asarray(xrp), reply >= 0)
+    np.testing.assert_array_equal(
+        np.where(np.asarray(xrp), np.asarray(xnf), -1), reply)
+
+
+@pytest.mark.parametrize("n", [37, 1024])
+def test_makeup_slot_matches_reference(n):
+    """process_makeup_slot against simulator.go:66-75 node by node: under
+    fanin the requester is appended; otherwise a uniformly drawn friend
+    is evicted (sent a breakup) and the requester takes its place."""
+    import jax
+
+    k, fanin = 5, 3
+    fr, cnt, src, has, ids, kk = _random_state(n, k, seed=8)
+    xf, xc, xv, xev = ov.process_makeup_slot(fanin, fr, cnt, src, has, kk)
+    vpos = np.asarray(jax.random.randint(kk, cnt.shape, 0,
+                                         jnp.maximum(cnt, 1),
+                                         dtype=jnp.int32))
+    f, c, src, has = (np.array(a) for a in (fr, cnt, src, has))
+    victim = np.full(n, -1)
+    for r in range(n):
+        if not has[r]:
+            continue
+        if c[r] < fanin:
+            f[r, min(c[r], k - 1)] = src[r]
+            c[r] += 1
+        else:
+            victim[r] = f[r, vpos[r]]
+            f[r, vpos[r]] = src[r]
+    assert (victim >= 0).any() and (np.asarray(xc) > np.asarray(cnt)).any()
+    np.testing.assert_array_equal(np.asarray(xf), f)
+    np.testing.assert_array_equal(np.asarray(xc), c)
+    np.testing.assert_array_equal(np.asarray(xev), victim >= 0)
+    np.testing.assert_array_equal(
+        np.where(np.asarray(xev), np.asarray(xv), -1), victim)
+
+
+@pytest.mark.parametrize("n", [37, 1024])
+def test_request_round_matches_reference(n):
+    """The rounds engine's bootstrap block (needNewFriend,
+    simulator.go:95-106): every node under fanout appends its drawn peer
+    and sends it a makeup; the round counts the requests."""
+    import jax
+
+    from gossip_simulator_tpu.utils import rng as _rng
+
+    I32 = jnp.int32
+    k, fanout = 5, 3
+    fr, cnt, _, _, ids, kk = _random_state(n, k, seed=9)
+    w = jax.random.randint(jax.random.fold_in(kk, _rng.OP_BOOTSTRAP),
+                           (n,), 0, n, dtype=I32)
+    w = jnp.where(w == ids, (w + 1) % n, w)
+    under = cnt < fanout
+    xf = ov._masked_set(fr, ids, jnp.minimum(cnt, k - 1), w, under)
+    xc = cnt + under.astype(I32)
+    xem = jnp.where(under, w, -1)
+    f, c, w = (np.array(a) for a in (fr, cnt, w))
+    em = np.full(n, -1)
+    for r in range(n):
+        if c[r] < fanout:
+            assert w[r] != r
+            f[r, c[r]] = w[r]
+            c[r] += 1
+            em[r] = w[r]
+    assert 0 < (em >= 0).sum() < n
+    np.testing.assert_array_equal(np.asarray(xf), f)
+    np.testing.assert_array_equal(np.asarray(xc), c)
+    np.testing.assert_array_equal(np.asarray(xem), em)
+    assert int(under.sum(dtype=I32)) == int((em >= 0).sum())
+
+
+@pytest.mark.parametrize("m", [133, 2048])
+def test_hosted_delivery_matches_reference(m):
+    """make_hosted_column_delivery counting each row's live lanes itself
+    (no caller totals) on a width ladder: sparse, empty and fully valid
+    emission rows, filled into a NumPy rank-major mailbox in row then
+    sender order, overflow past cap counted as dropped."""
+    from gossip_simulator_tpu.ops.mailbox import make_hosted_column_delivery
+
+    rng = np.random.default_rng(19)
+    cap = 3
+    rows = [np.where(rng.random(m) < 0.4, rng.integers(0, m, m), -1)
+            for _ in range(4)]
+    rows += [np.full(m, -1), rng.integers(0, m, m)]
+    mat = np.stack(rows)
+    run = make_hosted_column_delivery(m, cap, (32, 128, m),
+                                      per_call_chunks=2)
+    mbox, load, dropped = run((jnp.asarray(mat, jnp.int32),))
+    want = np.full(m * cap, -1)
+    cnt = np.zeros(m, np.int64)
+    drops = 0
+    for c in range(mat.shape[0]):
+        for s in range(m):
+            d = mat[c, s]
+            if d < 0:
+                continue
+            if cnt[d] < cap:
+                want[cnt[d] * m + d] = s
+            else:
+                drops += 1
+            cnt[d] += 1
+    assert drops > 0
+    np.testing.assert_array_equal(np.asarray(mbox)[:m * cap], want)
+    assert int(load) == min(int(cnt.max()), cap)
+    assert int(dropped) == drops

@@ -313,7 +313,7 @@ AB_COMBOS = {
 # pays a host callback per ref access: a sharded combo (8 virtual devices)
 # took ~30 min here (PR 21, sharded_event passed), far past the budgeted
 # tier-1 sweep, so the sharded combos ride the explicit tier1.yml step
-# for this file (no marker filter) -- the test_overlay_kernel policy.
+# for this file (no marker filter).
 @pytest.mark.parametrize("name", [
     pytest.param(n, marks=pytest.mark.slow) if n.startswith("sharded")
     else n for n in sorted(AB_COMBOS)])

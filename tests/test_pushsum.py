@@ -209,7 +209,7 @@ def test_metric_reaches_eps_and_stamps_tick():
     # off-TPU: the jax leg alone ran >15 min here (PR 21), so the full
     # four-combo matrix rides this file's explicit tier1.yml step (no
     # marker filter) instead of the budgeted sweep.  The kernels' own
-    # parity tests stay in the sweep (test_pallas_deliver/megakernel).
+    # parity tests stay in the sweep (test_pallas_deliver).
     pytest.param(("xla", "pallas"), marks=pytest.mark.slow, id="pallas"),
 ])
 def test_converges_under_churn_all_engine_combos(kernels):

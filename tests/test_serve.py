@@ -259,14 +259,21 @@ def test_serve_reshard_mid_churn_with_healing(backend, force):
 def test_sigterm_lands_checkpoint_and_interrupted_result(tmp_path):
     """Kill a live CLI run with SIGTERM: exit code 2 (not-converged), a
     final atomic snapshot in the checkpoint dir, and a run-dir result
-    with reason "interrupted" -- the long-lived serving contract."""
+    with reason "interrupted" -- the long-lived serving contract.
+
+    The run must not end before the signal lands: a few nodes of this
+    k-out graph have no in-edges, so coverage 1.0 is out of reach, and
+    -overlay-heal on never reports an empty ring as exhausted, so the
+    job would only stop at max_rounds (10 000 windows, minutes on a
+    CPU)."""
     ckpt_dir = str(tmp_path / "ckpt")
     run_dir = str(tmp_path / "run")
     args = [sys.executable, "-m", "gossip_simulator_tpu",
             "-n", "2000", "-graph", "kout", "-fanout", "6", "-seed", "3",
             "-crashrate", "0", "-backend", "jax", "-engine", "event",
             "-rumors", "32", "-traffic", "stream", "-stream-rate", "5",
-            "-coverage-target", "0.99", "-checkpoint-every", "1",
+            "-coverage-target", "1.0", "-overlay-heal", "on",
+            "-checkpoint-every", "1",
             "-checkpoint-dir", ckpt_dir, "-run-dir", run_dir]
     proc = subprocess.Popen(args, env=dict(os.environ),
                             stdout=subprocess.DEVNULL,

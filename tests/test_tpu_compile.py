@@ -100,22 +100,6 @@ def _graph(sds):
             (sds(()),))
 
 
-def _megakernel(sds):
-    from gossip_simulator_tpu.ops import pallas_megakernel as mk
-
-    return (lambda acc, ids, mass, slot, m: mk.fused_drain_sum(
-        acc, ids, mass, slot, m, cap=ROWS // 4, b=4, interpret=False),
-        (sds((ROWS, 2)), sds((ROWS,)), sds((ROWS, 2)), sds(()), sds(())))
-
-
-def _overlay(sds):
-    from gossip_simulator_tpu.ops import pallas_overlay_kernel as pok
-
-    return (lambda fr, cnt, src, has, draw: pok.fused_negotiate(
-        fr, cnt, src, has, draw, kind="makeup", limit=6, interpret=False),
-        (sds((ROWS, 8)),) + tuple(sds((ROWS,)) for _ in range(4)))
-
-
 def _refused(msg):
     return pytest.mark.xfail(strict=True, reason=f"v5e compiler: {msg}")
 
@@ -124,11 +108,6 @@ def _refused(msg):
     pytest.param(_deliver, id="deliver",
                  marks=_refused("Cannot store scalars to VMEM")),
     pytest.param(_graph, id="graph"),
-    pytest.param(_megakernel, id="megakernel",
-                 marks=_refused("Cannot store scalars to VMEM")),
-    pytest.param(_overlay, id="overlay",
-                 marks=_refused("Mosaic: cannot statically prove that index "
-                                "in dimension 0 is a multiple of 1024")),
 ])
 def test_pallas_family_compiles_for_v5e(build, one_chip, no_cache):
     fn, args = build(lambda shape: jax.ShapeDtypeStruct(
